@@ -114,15 +114,20 @@ def _cells_chunk_impl(state, arr_chunk, floor_chunk, lam0, gaps, alive_tab,
         # drops its ghost records — so the retried share is counted at
         # both its optimistic pre-fail and its true post-fail delay; the
         # measured parity band absorbs this.)
-        moved = jnp.einsum("c,cf->f", died, st[1] + st[2])
+        # The two cell-axis contractions here are broadcast-multiply-sums,
+        # not einsums: a float32 dot at default precision runs as one
+        # bfloat16 pass on a TPU, which would round per-tick arrival counts
+        # above 256 and break the router's flow conservation.  With C <= 16
+        # cells the elementwise form is cheap, and exact in float32.
+        moved = (died[:, None] * (st[1] + st[2])).sum(0)
         st = mask_state(st, alive)
         fail_d = failover_dist(alive, rs)
         # router from previous-tick state
         slots = st[0].sum(-1) * pol["cc"]
         free = jnp.maximum(slots - st[1].sum(-1), 0.0)
         s = spill_fraction(st[2].sum(-1), a_t.sum(-1), slots, thr) * alive
-        routed = jnp.einsum("cd,cf->df",
-                            flux_matrix(alive, s, free, fail_d), a_t) \
+        flux = flux_matrix(alive, s, free, fail_d)
+        routed = (flux[:, :, None] * a_t[:, None, :]).sum(0) \
             + fail_d[:, None] * moved[None, :]
         # per-cell fleet bounds: scheduled + reactive floors raise
         # min_nodes; a dead cell's bounds collapse to (0, 0)

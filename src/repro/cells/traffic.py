@@ -12,9 +12,8 @@ served by cell d this tick:
   by the same ``route_skew`` preference the origin weights use.
 
 Every row sums to exactly 1 (mass conservation — pinned by
-``tests/test_cells.py``), so the routed arrival matrix
-``einsum('cd,cf->df', M, arr)`` redistributes, never creates or destroys,
-load.  The fluid engine traces this math inside the chunked scan
+``tests/test_cells.py``), so the routed arrival matrix ``M^T arr``
+redistributes, never creates or destroys, load.  The fluid engine traces this math inside the chunked scan
 (``route_skew`` and ``spill_threshold`` are traced policy axes, hence
 sweepable batch dimensions); the oracle uses the numpy twin to split
 redirected arrivals at failover time.

@@ -57,13 +57,13 @@ State is (F,)-vectorized; policies are branchless jnp.  dt = 1s.
 from __future__ import annotations
 
 import dataclasses
+import sys
 from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.eventsim import SimConfig
@@ -704,6 +704,13 @@ def _phi(z: np.ndarray) -> np.ndarray:
 # per-request service times are clipped lognormals (see trace.synthesize)
 _DUR_FLOOR, _DUR_CAP = 0.02, 30.0
 
+#: a function's request count is a whole number, but the cells router splits
+#: arrivals into fractional per-cell shares whose float32 sums land within
+#: rounding of it — on either side, depending on the device's summation
+#: order.  A count this close to ``min_requests`` meets the threshold, so
+#: which functions enter the geomean does not depend on the device.
+_COUNT_SLACK = 1e-3
+
 
 def _slowdown_geomean(hist, arrtot, edges, dur_median, dur_sigma, warm,
                       min_requests: int = 5, q: float = 0.99,
@@ -735,7 +742,7 @@ def _slowdown_geomean(hist, arrtot, edges, dur_median, dur_sigma, warm,
     n_eff = np.asarray(arrtot, np.float64)
     if fn_weights is not None:
         n_eff = n_eff / np.maximum(np.asarray(fn_weights, np.float64), 1e-12)
-    keep = n_eff >= min_requests
+    keep = n_eff >= min_requests - _COUNT_SLACK
     if not keep.any():
         return float("nan")
     h = np.asarray(hist)[keep]
@@ -822,7 +829,7 @@ def _slowdown_geomean_jax(hist, arrtot, edges, dur_median, dur_sigma, warm,
     w_np = None if fn_weights is None else np.asarray(fn_weights, np.float64)
     if w_np is not None:
         n_eff = n_eff / np.maximum(w_np, 1e-12)
-    keep = n_eff >= min_requests
+    keep = n_eff >= min_requests - _COUNT_SLACK
     if not keep.any():
         return float("nan")
     h = np.asarray(hist, np.float32)[keep]
@@ -1045,8 +1052,8 @@ def _chunk_batch_fnshard_impl(state, arr_chunk, lam0, gaps, gap_tab, dur,
     out_stats = (fP, fP, rep, rep)
     if telem:
         out_stats = out_stats + (rep, rep, rep)
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=(st_specs, out_stats), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=(st_specs, out_stats), check_vma=False)
     return sharded(state, arr_chunk, lam0, gaps, gap_tab, dur, mem, billed_w,
                    pols, fleets, edges, tick0, weights)
 
@@ -1085,7 +1092,9 @@ def _chunked_summaries(trace, policy: JaxPolicy, pols: dict,
     couple functions through per-tick capacity reductions, so they shard
     the POINT axis instead — the largest divisor of the point count that
     fits the device budget, falling back to the unsharded dispatch when the
-    batch cannot split.  ``devices=0`` is the legacy single-device path."""
+    batch cannot split; a stderr note says when fewer devices than
+    requested carry the batch.  ``devices=0`` is the legacy single-device
+    path."""
     arr_np = np.asarray(rate_matrix(trace, dt))
     n_ticks, f = arr_np.shape
     dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(trace, policy, sim, dt)
@@ -1130,6 +1139,9 @@ def _chunked_summaries(trace, policy: JaxPolicy, pols: dict,
             f += pad
     elif devices > 0 and has_fleet:
         d = _largest_divisor(n_points, devices)
+        if devices > 1 and d < devices:
+            print(f"simjax: {n_points} point(s) split over {d} of the "
+                  f"{devices} requested device(s)", file=sys.stderr)
         if d > 1:
             from repro.distributed.sharding import device_mesh
             pt_sharding = NamedSharding(device_mesh(d, "points"), P("points"))

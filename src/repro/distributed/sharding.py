@@ -137,11 +137,19 @@ def device_mesh(devices: int, axis: str) -> Mesh:
     if devices < 1:
         raise ValueError(f"device_mesh needs >= 1 device, got {devices}")
     if devices > len(avail):
-        raise ValueError(
-            f"device_mesh({devices}, {axis!r}): only {len(avail)} local "
-            f"device(s) visible — on CPU set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={devices}")
+        raise ValueError(f"device_mesh({devices}, {axis!r}): "
+                         f"{too_few_devices(devices, avail)}")
     return Mesh(np.asarray(avail[:devices]), (axis,))
+
+
+def too_few_devices(wanted: int, avail) -> str:
+    """Explain a device request the host cannot meet; only the CPU backend
+    can be given more (virtual) devices, through ``XLA_FLAGS``."""
+    msg = f"only {len(avail)} local device(s) visible"
+    if avail and avail[0].platform == "cpu":
+        msg += (f" — on CPU set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={wanted}")
+    return msg
 
 
 def sanitize_spec(spec: P, shape: Sequence[int], mesh: Mesh) -> P:

@@ -1,13 +1,19 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-if os.environ.get("REPRO_XLA_EXTRA"):  # e.g. --xla_dump_to=... for debugging
-    os.environ["XLA_FLAGS"] += " " + os.environ["REPRO_XLA_EXTRA"]
+if __name__ == "__main__":
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    if os.environ.get("REPRO_XLA_EXTRA"):  # e.g. --xla_dump_to=... to debug
+        os.environ["XLA_FLAGS"] += " " + os.environ["REPRO_XLA_EXTRA"]
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 extract the roofline terms from the compiled artifact.
 
-The two lines above MUST stay the first statements in this module (before
-any jax import) — jax locks the device count on first init.
+Run as a program, the lines above MUST stay the first statements in this
+module (before any jax import) — jax locks the platform and the device
+count on first init.  The dry-run runs on the CPU's virtual devices only,
+and its subprocesses inherit that environment, so neither it nor its
+children ever claim an accelerator.  Importing the module (for its peak
+constants) leaves the environment alone.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-4b --shape train_4k
@@ -177,4 +183,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

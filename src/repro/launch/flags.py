@@ -46,9 +46,9 @@ def add_run_flags(ap: argparse.ArgumentParser, *,
                          "see --list for registered tiers")
     ap.add_argument("--devices", type=int, default=0, metavar="N",
                     help="shard the fluid scan over N local devices "
-                         "(0 = unsharded; on CPU expose devices with "
-                         "XLA_FLAGS=--xla_force_host_platform_device_count"
-                         "=N)")
+                         "(0 = unsharded; a CPU-only host exposes N "
+                         "virtual devices with XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N)")
     ap.add_argument("--cluster", type=float, default=0.0, metavar="RPS",
                     help="bucket functions below this mean-rps threshold "
                          "into weighted super-functions before simulating "
@@ -102,12 +102,11 @@ def validate_run_flags(args: argparse.Namespace) -> int:
         return 2
     if args.devices > 0:
         import jax
-        n = len(jax.devices())
-        if args.devices > n:
-            print(f"--devices {args.devices}: only {n} local device(s) "
-                  f"visible — on CPU set XLA_FLAGS="
-                  f"--xla_force_host_platform_device_count={args.devices}",
-                  file=sys.stderr)
+        devs = jax.devices()
+        if args.devices > len(devs):
+            from repro.distributed.sharding import too_few_devices
+            print(f"--devices {args.devices}: "
+                  f"{too_few_devices(args.devices, devs)}", file=sys.stderr)
             return 2
     if args.cluster < 0.0:
         print(f"--cluster must be >= 0 (a mean-rps threshold), got "

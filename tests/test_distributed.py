@@ -23,7 +23,7 @@ def _abstract_mesh(shape=(16, 16), names=("data", "model")):
     # spec computation works on an AbstractMesh: the production 16x16 shape
     # without needing 256 devices
     from jax.sharding import AbstractMesh
-    return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def test_fsdp_specs_sharding_first_free_dim():

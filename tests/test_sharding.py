@@ -90,6 +90,31 @@ def test_point_axis_sharding_one_device(trace):
             assert rb[k] == rs[k], k
 
 
+def test_point_axis_fallback_is_reported(trace, capsys):
+    # 3 points cannot split over 2 devices: the batch runs on one, and says
+    # so instead of quietly using fewer devices than asked for
+    jf = JaxFleet(node_memory_mb=8192.0)
+    pts = [{"keepalive_s": float(ka)} for ka in (60.0, 300.0, 600.0)]
+    base = evaluate_points(trace, JaxPolicy(kind=0), jf, pts)
+    assert "split over" not in capsys.readouterr().err
+    shard = evaluate_points(trace, JaxPolicy(kind=0), jf, pts, devices=2)
+    assert "3 point(s) split over 1 of the 2 requested" \
+        in capsys.readouterr().err
+    for rb, rs in zip(base, shard):
+        for k in PARITY_KEYS:
+            assert rb[k] == rs[k], k
+
+
+def test_device_hint_names_xla_flags_only_on_cpu():
+    from types import SimpleNamespace
+
+    from repro.distributed.sharding import too_few_devices
+    cpu = too_few_devices(4, [SimpleNamespace(platform="cpu")])
+    assert "xla_force_host_platform_device_count=4" in cpu
+    tpu = too_few_devices(4, [SimpleNamespace(platform="tpu")])
+    assert tpu == "only 1 local device(s) visible"
+
+
 @multi_device
 def test_point_axis_sharding_multi_device(trace):
     jf = JaxFleet(node_memory_mb=8192.0)

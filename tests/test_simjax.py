@@ -78,3 +78,42 @@ def test_simjax_scales_to_thousands_of_functions():
     s = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7)))
     assert np.isfinite(s["slowdown_geomean_p99"])
     assert s["instances_mean"] > 10
+
+
+def test_import_raises_no_deprecation_warning():
+    # the sharded dispatch uses jax.shard_map, not the deprecated
+    # jax.experimental.shard_map module
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(src)}
+    subprocess.run([sys.executable, "-W", "error::DeprecationWarning", "-c",
+                    "import repro.core.simjax"], env=env, check=True,
+                   timeout=120)
+
+
+@pytest.mark.parametrize("solver", ["numpy", "jax"])
+def test_request_threshold_ignores_rounding(solver):
+    # the cells router splits whole arrivals into fractional per-cell shares,
+    # so a function's request count can come back one float32 step short of
+    # min_requests; it still counts, or the geomean would depend on the
+    # device's summation order
+    from repro.core import simjax
+    if solver == "numpy":
+        p99 = simjax._slowdown_geomean
+    else:
+        def p99(*args):
+            return simjax._slowdown_geomean_jax(*args, 5, 0.99, True, None)
+    edges = simjax._delay_edges(256)
+    hist = np.zeros((2, 256))
+    hist[0, 0], hist[1, 0], hist[1, 150] = 20.0, 4.0, 1.0
+    dm, ds = np.full(2, 0.5), np.full(2, 1.0)
+
+    def geo(count):
+        return p99(hist, np.array([20.0, count]), edges, dm, ds, 0.001)
+
+    short = float(np.nextafter(np.float32(5.0), np.float32(0.0)))
+    assert geo(short) == pytest.approx(geo(5.0), rel=1e-6)
+    assert geo(4.0) != geo(5.0)          # a whole request short is out
