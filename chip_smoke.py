@@ -27,8 +27,8 @@ point-sharded fleet sweep.  Both must agree within ``SHARD_TOL`` and the
 sharded state must span four devices.
 
 Every phase prints one JSON line: the platform and kind of the devices
-its result arrays lived on, wall and compile seconds of each leg, and the
-largest relative chip-vs-reference gap.  Only a full pass prints the last
+its result arrays lived on, compile seconds of each leg, and the largest
+relative chip-vs-reference gap.  Only a full pass prints the last
 line, ``{"ok": true, "device": {...}}``.  Without a TPU, or without the
 repository's ``src/`` next to it, the script exits non-zero and prints no
 result.  It runs in one process and starts no other.
@@ -42,7 +42,6 @@ import json
 import math
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
@@ -127,21 +126,20 @@ def _observe(seen: dict):
 
 def _leg(device, fn):
     """Run ``fn`` with ``device`` as JAX's default device.  Returns its
-    result and a record of the leg: wall and compile seconds and where the
-    engine's arrays lived."""
+    result and a record of the leg: compile seconds and where the engine's
+    arrays lived."""
     import jax
     seen: dict = {}
-    c0, t0 = _compile_s[0], time.perf_counter()
+    c0 = _compile_s[0]
     with jax.default_device(device), _observe(seen):
         out = fn()
-    wall = time.perf_counter() - t0
     devs = sorted(seen["devices"], key=lambda d: d.id)
     if not devs:
         raise PhaseFailed("no engine step ran on any device")
     return out, {"platform": sorted({d.platform for d in devs}),
                  "kind": sorted({d.device_kind for d in devs}),
                  "devices": len(devs), "span": seen["span"],
-                 "wall_s": wall, "compile_s": _compile_s[0] - c0}
+                 "compile_s": _compile_s[0] - c0}
 
 
 # ---------------------------------------------------------------------------
@@ -313,14 +311,12 @@ def run(phases: dict, chip, cpu) -> bool:
     """Run every phase; print one JSON line each.  True when all passed."""
     ok = True
     for name, fn in phases.items():
-        t0 = time.perf_counter()
         try:
             rec = {"phase": name, "ok": True, **fn(chip, cpu)}
         except Exception as e:  # a failed phase must not hide the others
             ok = False
             rec = {"phase": name, "ok": False,
                    "error": f"{type(e).__name__}: {e}"}
-        rec["phase_wall_s"] = time.perf_counter() - t0
         print(json.dumps(rec, default=str), flush=True)
     return ok
 

@@ -39,9 +39,11 @@ import numpy as np
 
 from repro.core.simjax import (_ACC_NAMES, _PFLEET, _acc_summary,
                                _billed_weights, _delay_edges, _init_state,
-                               _make_step, _prep_static, stack_params)
+                               _make_step, _prep_static, _read_back,
+                               _upload_chunks, stack_params)
 from repro.core.policy_api import get_family
 from repro.core.trace import gap_statistics, rate_matrix
+from repro.obs import runs
 
 from repro.cells.topology import CellTopology
 from repro.cells.traffic import failover_dist, flux_matrix, spill_fraction
@@ -144,8 +146,9 @@ def _cells_chunk_impl(state, arr_chunk, floor_chunk, lam0, gaps, alive_tab,
                 axis=1)
         else:
             fleet_cells = jnp.broadcast_to(fleet, (c_n, fleet.shape[0]))
-        st, ys = jax.vmap(one_cell)(routed, st, fleet_cells, lam0, gaps,
-                                    alive_tab, tail_tab, static_nodes)
+        with jax.named_scope("fluid_tick"):
+            st, ys = jax.vmap(one_cell)(routed, st, fleet_cells, lam0, gaps,
+                                        alive_tab, tail_tab, static_nodes)
         # reactive triggers read this tick's utilization; the raised floor
         # binds from the NEXT tick (a one-tick actuation lag, matching the
         # oracle's once-per-tick reconcile)
@@ -165,10 +168,12 @@ def _cells_chunk_impl(state, arr_chunk, floor_chunk, lam0, gaps, alive_tab,
         m = ((g >= warm_tick) & (g < total_ticks)).astype(jnp.float32)
         delay, arr, arr_delayed = ys[0], ys[1], ys[2]
         wmask = m * alive[:, None]
-        b = jnp.clip(jnp.searchsorted(edges, delay.reshape(-1),
-                                      side="right"), 0, nbins - 1)
-        hist = hist.at[rows_flat, b].add((arr_delayed * wmask).reshape(-1))
-        hist = hist.at[:, 0].add(((arr - arr_delayed) * wmask).sum(0))
+        with jax.named_scope("delay_histogram"):
+            b = jnp.clip(jnp.searchsorted(edges, delay.reshape(-1),
+                                          side="right"), 0, nbins - 1)
+            hist = hist.at[rows_flat, b].add(
+                (arr_delayed * wmask).reshape(-1))
+            hist = hist.at[:, 0].add(((arr - arr_delayed) * wmask).sum(0))
         arrtot = arrtot + (arr * wmask).sum(0)
         ysc = jnp.stack(ys[3:3 + len(_ACC_NAMES)]) * alive[None, :]
         return (st, fr, ft, tcool, hist, arrtot,
@@ -189,7 +194,10 @@ def _cells_chunk_batch_impl(state, arr_chunk, floor_chunk, lam0, gaps,
                             fleets, trig, cpu_consts, static_nodes, edges,
                             tick0, **statics):
     """One time chunk for a batch of parameter points (vmap over the point
-    axis of state/pols/fleets, every per-cell input shared)."""
+    axis of state/pols/fleets, every per-cell input shared).  The body runs
+    once per trace, which the open engine run counts as ``step_traces``."""
+    runs.count("step_traces", 1)
+
     def one(st, p, fl):
         return _cells_chunk_impl(st, arr_chunk, floor_chunk, lam0, gaps,
                                  alive_tab, tail_tab, dur, mem, billed_w,
@@ -227,88 +235,104 @@ def cells_chunked_summaries(traces, topo: CellTopology, policy, pols,
     if (topo.scheduled or topo.reactive) and not has_fleet:
         raise ValueError("cell triggers drive the node fleet: the scenario "
                          "needs a fleet for scheduled/reactive triggers")
-    mats = [np.asarray(rate_matrix(tr, dt)) for tr in traces]
-    arr_np = np.stack(mats, axis=1)                     # (T, C, F)
-    n_ticks, _, f = arr_np.shape
-    duration_s = traces[0].duration_s
-    dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(
-        traces[0], policy, sim, dt)
-    billed_w = _billed_weights(traces[0], billing)      # profile-wide
-    dur_median = np.asarray(traces[0].profile.dur_median)
-    dur_sigma = np.asarray(traces[0].profile.dur_sigma)
-    prov_ticks = max(1, int(round(provision_s / dt)))
-    edges = _delay_edges(nbins)
-    edges_j = jnp.asarray(edges)
-    warm_tick = int(n_ticks * warmup_frac)
-    chunk_ticks = max(1, min(chunk_ticks, n_ticks))
-    n_points = fleets.shape[0]
+    with runs.run(points=int(fleets.shape[0]), devices=0, cells=c_n) as info:
+        with runs.span("prep.rate_matrix"):
+            mats = [np.asarray(rate_matrix(tr, dt)) for tr in traces]
+            arr_np = np.stack(mats, axis=1)                 # (T, C, F)
+        n_ticks, _, f = arr_np.shape
+        chunk_ticks = max(1, min(chunk_ticks, n_ticks))
+        info.update(functions=f, ticks=n_ticks, chunk_ticks=chunk_ticks)
+        gq_l, at_l, tt_l = zip(*(gap_statistics(tr) for tr in traces))
 
-    lam0 = jnp.asarray(np.stack([m.mean(axis=0) / dt for m in mats]),
-                       jnp.float32)                     # (C, F)
-    gq_l, at_l, tt_l = zip(*(gap_statistics(tr) for tr in traces))
-    gaps = jnp.asarray(np.stack(gq_l), jnp.float32)
-    alive_tab = jnp.asarray(np.stack(at_l), jnp.float32)
-    tail_tab = jnp.asarray(np.stack(tt_l), jnp.float32)
+        with runs.span("prep.device"):
+            duration_s = traces[0].duration_s
+            dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(
+                traces[0], policy, sim, dt)
+            billed_w = _billed_weights(traces[0], billing)  # profile-wide
+            dur_median = np.asarray(traces[0].profile.dur_median)
+            dur_sigma = np.asarray(traces[0].profile.dur_sigma)
+            prov_ticks = max(1, int(round(provision_s / dt)))
+            edges = _delay_edges(nbins)
+            edges_j = jnp.asarray(edges)
+            warm_tick = int(n_ticks * warmup_frac)
+            n_points = fleets.shape[0]
 
-    ft_s = topo.fail_time(duration_s)
-    fail_tick = -1 if ft_s is None else int(round(ft_s / dt))
-    floor_np = topo.floor_schedule(n_ticks, dt, duration_s)   # (T, C)
-    k = len(topo.reactive)
-    trig = None
-    if k:
-        trig = (jnp.asarray([t.util_high for t in topo.reactive],
-                            jnp.float32),
-                jnp.asarray([t.change for t in topo.reactive], jnp.float32),
-                jnp.asarray([max(1, round(t.hold_s / dt))
-                             for t in topo.reactive], jnp.float32),
-                jnp.asarray([max(1, round(t.cooldown_s / dt))
-                             for t in topo.reactive], jnp.float32))
-    static_nodes = jnp.asarray(topo.cell_nodes(num_nodes), jnp.float32)
+            lam0 = jnp.asarray(np.stack([m.mean(axis=0) / dt for m in mats]),
+                               jnp.float32)                 # (C, F)
+            gaps = jnp.asarray(np.stack(gq_l), jnp.float32)
+            alive_tab = jnp.asarray(np.stack(at_l), jnp.float32)
+            tail_tab = jnp.asarray(np.stack(tt_l), jnp.float32)
 
-    fleets_j = jnp.asarray(fleets, jnp.float32)
-    pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), pols)
+            ft_s = topo.fail_time(duration_s)
+            fail_tick = -1 if ft_s is None else int(round(ft_s / dt))
+            floor_np = topo.floor_schedule(n_ticks, dt, duration_s)  # (T, C)
+            k = len(topo.reactive)
+            trig = None
+            if k:
+                trig = (jnp.asarray([t.util_high for t in topo.reactive],
+                                    jnp.float32),
+                        jnp.asarray([t.change for t in topo.reactive],
+                                    jnp.float32),
+                        jnp.asarray([max(1, round(t.hold_s / dt))
+                                     for t in topo.reactive], jnp.float32),
+                        jnp.asarray([max(1, round(t.cooldown_s / dt))
+                                     for t in topo.reactive], jnp.float32))
+            static_nodes = jnp.asarray(topo.cell_nodes(num_nodes),
+                                       jnp.float32)
 
-    def init_point(fl):
-        def init_cell(sn):
-            return _init_state(f, cold_ticks, wbuf, prov_ticks,
-                               fl[0] if has_fleet else sn)
-        return (jax.vmap(init_cell)(static_nodes), jnp.zeros((c_n, k)),
-                jnp.zeros((c_n, k)), jnp.zeros((c_n, k)))
+            fleets_j = jnp.asarray(fleets, jnp.float32)
+            pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
+                                  pols)
 
-    state = jax.vmap(init_point)(fleets_j)
-    hist = np.zeros((n_points, f, nbins))
-    arrtot = np.zeros((n_points, f))
-    sums = np.zeros((n_points, len(_ACC_NAMES)))
-    n = np.zeros(n_points)
-    csums = np.zeros((n_points, c_n, len(_ACC_NAMES)))
-    cn = np.zeros((n_points, c_n))
-    for t0 in range(0, n_ticks, chunk_ticks):
-        a = arr_np[t0:t0 + chunk_ticks]
-        fl_c = floor_np[t0:t0 + chunk_ticks]
-        if a.shape[0] < chunk_ticks:        # pad the tail chunk (masked out)
-            pad = chunk_ticks - a.shape[0]
-            a = np.concatenate([a, np.zeros((pad, c_n, f), a.dtype)])
-            fl_c = np.concatenate([fl_c, np.zeros((pad, c_n), fl_c.dtype)])
-        state, out = _cells_chunk_batch(
-            state, jnp.asarray(a), jnp.asarray(fl_c), lam0, gaps, alive_tab,
-            tail_tab, dur, mem, billed_w, pols_j, fleets_j, trig, cpu_consts,
-            static_nodes, edges_j, jnp.asarray(t0, jnp.int32),
-            warm_tick=warm_tick, total_ticks=n_ticks, family=policy.family,
-            dt=dt, cold_ticks=cold_ticks, wbuf=wbuf, prov_ticks=prov_ticks,
-            has_fleet=has_fleet, fail_cell=int(topo.fail_cell),
-            fail_tick=fail_tick, route_skew_static=float(topo.route_skew),
-            spill_static=float(topo.spill_threshold))
-        hist += np.asarray(out[0])
-        arrtot += np.asarray(out[1])
-        sums += np.asarray(out[2])
-        n += np.asarray(out[3])
-        csums += np.asarray(out[4])
-        cn += np.asarray(out[5])
-    iid = get_family(policy.family).synchronous_tail
-    rows = [_acc_summary(hist[i], arrtot[i], sums[i], n[i], edges,
-                         dur_median, dur_sigma, sim.warm_latency_s, dt,
-                         iid_tail=iid)
-            for i in range(n_points)]
+            def init_point(fl):
+                def init_cell(sn):
+                    return _init_state(f, cold_ticks, wbuf, prov_ticks,
+                                       fl[0] if has_fleet else sn)
+                return (jax.vmap(init_cell)(static_nodes),
+                        jnp.zeros((c_n, k)), jnp.zeros((c_n, k)),
+                        jnp.zeros((c_n, k)))
+
+            state = jax.vmap(init_point)(fleets_j)
+
+        hist = np.zeros((n_points, f, nbins))
+        arrtot = np.zeros((n_points, f))
+        sums = np.zeros((n_points, len(_ACC_NAMES)))
+        n = np.zeros(n_points)
+        csums = np.zeros((n_points, c_n, len(_ACC_NAMES)))
+        cn = np.zeros((n_points, c_n))
+        for index, t0 in enumerate(range(0, n_ticks, chunk_ticks)):
+            with runs.span("chunk", index=index):
+                a, fl_c = _upload_chunks(arr_np[t0:t0 + chunk_ticks],
+                                         floor_np[t0:t0 + chunk_ticks],
+                                         ticks=chunk_ticks)
+                with runs.span("chunk.dispatch"):
+                    state, out = _cells_chunk_batch(
+                        state, a, fl_c, lam0, gaps, alive_tab, tail_tab, dur,
+                        mem, billed_w, pols_j, fleets_j, trig, cpu_consts,
+                        static_nodes, edges_j, jnp.asarray(t0, jnp.int32),
+                        warm_tick=warm_tick, total_ticks=n_ticks,
+                        family=policy.family, dt=dt, cold_ticks=cold_ticks,
+                        wbuf=wbuf, prov_ticks=prov_ticks,
+                        has_fleet=has_fleet, fail_cell=int(topo.fail_cell),
+                        fail_tick=fail_tick,
+                        route_skew_static=float(topo.route_skew),
+                        spill_static=float(topo.spill_threshold))
+                out = _read_back(out)
+                with runs.span("chunk.accumulate"):
+                    hist += out[0]
+                    arrtot += out[1]
+                    sums += out[2]
+                    n += out[3]
+                    csums += out[4]
+                    cn += out[5]
+        iid = get_family(policy.family).synchronous_tail
+        rows = []
+        for i in range(n_points):
+            with runs.span("summary", point=i):
+                rows.append(_acc_summary(hist[i], arrtot[i], sums[i], n[i],
+                                         edges, dur_median, dur_sigma,
+                                         sim.warm_latency_s, dt,
+                                         iid_tail=iid))
     if detail is not None:
         detail["cell_rows"] = _cell_rows(csums[0], cn[0], dt)
     return rows

@@ -70,6 +70,7 @@ from repro.core.eventsim import SimConfig
 from repro.core.policy_api import (HYBRID_MIN_KA_S, PolicyObs,  # noqa: F401
                                    get_family)
 from repro.core.trace import Trace, gap_statistics, rate_matrix
+from repro.obs import runs
 from repro.obs.telemetry import TELEM_ATTR, TELEM_SERIES, assemble_telemetry
 
 
@@ -736,9 +737,19 @@ def _slowdown_geomean(hist, arrtot, edges, dur_median, dur_sigma, warm,
     2000-function fig9 replay and below keep the float64 numpy path
     bit-for-bit."""
     if np.asarray(hist).size >= _JAX_SOLVER_MIN_CELLS:
-        return _slowdown_geomean_jax(hist, arrtot, edges, dur_median,
-                                     dur_sigma, warm, min_requests, q,
-                                     iid_tail, fn_weights)
+        with runs.span("summary.solve", on="device"):
+            return _slowdown_geomean_jax(hist, arrtot, edges, dur_median,
+                                         dur_sigma, warm, min_requests, q,
+                                         iid_tail, fn_weights)
+    with runs.span("summary.solve", on="host"):
+        return _slowdown_geomean_np(hist, arrtot, edges, dur_median,
+                                    dur_sigma, warm, min_requests, q,
+                                    iid_tail, fn_weights)
+
+
+def _slowdown_geomean_np(hist, arrtot, edges, dur_median, dur_sigma, warm,
+                         min_requests, q, iid_tail, fn_weights) -> float:
+    """The float64 numpy bisection behind ``_slowdown_geomean``."""
     n_eff = np.asarray(arrtot, np.float64)
     if fn_weights is not None:
         n_eff = n_eff / np.maximum(np.asarray(fn_weights, np.float64), 1e-12)
@@ -814,7 +825,8 @@ def _bisect_slowdown(p, wrow, q_eff, log_med, sig, hi0, iters=60):
         ok = (p * sf).sum(axis=1) >= q_eff
         return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
 
-    lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    with jax.named_scope("slowdown_bisect"):
+        lo, hi = jax.lax.fori_loop(0, iters, body, (lo, hi))
     return 0.5 * (lo + hi)
 
 
@@ -882,7 +894,8 @@ def _chunk_impl(state, arr_chunk, lam0, gaps, gap_tab, dur, mem, billed_w,
 
     def acc_step(carry, i):
         st, hist, arrtot, sums, n = carry[:5]
-        st, ys = step(st, i)
+        with jax.named_scope("fluid_tick"):
+            st, ys = step(st, i)
         delay, arr, arr_delayed = ys[0], ys[1], ys[2]
         if weights is not None:
             # super-function multiplicity: the histogram counts REQUESTS,
@@ -890,9 +903,11 @@ def _chunk_impl(state, arr_chunk, lam0, gaps, gap_tab, dur, mem, billed_w,
             arr, arr_delayed = arr * weights, arr_delayed * weights
         g = tick0 + i
         m = ((g >= warm_tick) & (g < total_ticks)).astype(jnp.float32)
-        b = jnp.clip(jnp.searchsorted(edges, delay, side="right"), 0, nbins - 1)
-        hist = hist.at[jnp.arange(f), b].add(arr_delayed * m)
-        hist = hist.at[:, 0].add((arr - arr_delayed) * m)
+        with jax.named_scope("delay_histogram"):
+            b = jnp.clip(jnp.searchsorted(edges, delay, side="right"), 0,
+                         nbins - 1)
+            hist = hist.at[jnp.arange(f), b].add(arr_delayed * m)
+            hist = hist.at[:, 0].add((arr - arr_delayed) * m)
         out = (st, hist, arrtot + arr * m,
                sums + m * jnp.stack(ys[3:3 + len(_ACC_NAMES)]), n + m)
         if telem:
@@ -955,7 +970,11 @@ def _chunk_batch_impl(state, arr_chunk, lam0, gaps, gap_tab, dur, mem,
     """One time chunk for a whole batch of parameter points (vmap over the
     point axis of state/lam0/pols/fleets; ``pols`` is a STACKED params
     pytree — every leaf, scalar knob or weight array, carries a leading
-    point axis)."""
+    point axis).  The body runs once per trace of a step program (the
+    function-sharded one calls it from its shard body), which the open
+    engine run counts as ``step_traces``."""
+    runs.count("step_traces", 1)
+
     def one(st, l0, p, fl):
         return _chunk_impl(st, arr_chunk, l0, gaps, gap_tab, dur, mem,
                            billed_w, p, fl,
@@ -1036,10 +1055,11 @@ def _chunk_batch_fnshard_impl(state, arr_chunk, lam0, gaps, gap_tab, dur,
             family=family, dt=dt, cold_ticks=cold_ticks, wbuf=wbuf,
             prov_ticks=prov_ticks, has_fleet=False, telem_slots=telem_slots,
             weights=wt)
-        red = (out[0], out[1], jax.lax.psum(out[2], "functions"), out[3])
-        if telem:
-            red = red + (jax.lax.psum(out[4], "functions"), out[5],
-                         jax.lax.psum(out[6], "functions"))
+        with jax.named_scope("chunk_psum"):
+            red = (out[0], out[1], jax.lax.psum(out[2], "functions"), out[3])
+            if telem:
+                red = red + (jax.lax.psum(out[4], "functions"), out[5],
+                             jax.lax.psum(out[6], "functions"))
         return st, red
 
     fP = P(None, "functions")      # leading point axis, functions sharded
@@ -1095,133 +1115,183 @@ def _chunked_summaries(trace, policy: JaxPolicy, pols: dict,
     batch cannot split; a stderr note says when fewer devices than
     requested carry the batch.  ``devices=0`` is the legacy single-device
     path."""
-    arr_np = np.asarray(rate_matrix(trace, dt))
-    n_ticks, f = arr_np.shape
-    dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(trace, policy, sim, dt)
-    billed_w = _billed_weights(trace, billing)
-    dur_median = np.asarray(trace.profile.dur_median)
-    dur_sigma = np.asarray(trace.profile.dur_sigma)
-    weights_np = getattr(trace, "weights", None)
-    prov_ticks = max(1, int(round(provision_s / dt)))
-    edges = _delay_edges(nbins)
-    warm_tick = int(n_ticks * warmup_frac)
-    chunk_ticks = max(1, min(chunk_ticks, n_ticks))
     n_points = fleets.shape[0]
-
-    lam_np = arr_np.mean(axis=0) / dt
-    gq, alive_tab, tail_tab = gap_statistics(trace)
-
     devices = int(devices)
-    fn_mesh = pt_sharding = None
-    f_orig = f
-    if devices > 0 and not has_fleet:
-        from repro.distributed.sharding import device_mesh
-        fn_mesh = device_mesh(devices, "functions")
-        pad = (-f) % devices
-        if pad:
-            # inert padding functions: zero arrivals -> zero instances,
-            # creations, memory and histogram mass (trimmed below anyway)
-            arr_np = np.concatenate(
-                [arr_np, np.zeros((n_ticks, pad), arr_np.dtype)], axis=1)
-            dur = jnp.concatenate([dur, jnp.ones(pad, dur.dtype)])
-            mem = jnp.concatenate([mem, jnp.zeros(pad, mem.dtype)])
-            billed_w = jnp.concatenate([billed_w,
-                                        jnp.zeros(pad, billed_w.dtype)])
-            lam_np = np.concatenate([lam_np, np.zeros(pad)])
-            gq = np.concatenate([gq, np.full(pad, trace.duration_s)])
-            # never-observed-gap convention: alive = ka, tail = 1
-            from repro.core.trace import KA_GRID
-            alive_tab = np.concatenate(
-                [alive_tab, np.broadcast_to(KA_GRID, (pad, len(KA_GRID)))])
-            tail_tab = np.concatenate([tail_tab, np.ones((pad, len(KA_GRID)))])
-            if weights_np is not None:
-                weights_np = np.concatenate([weights_np, np.zeros(pad)])
-            f += pad
-    elif devices > 0 and has_fleet:
-        d = _largest_divisor(n_points, devices)
-        if devices > 1 and d < devices:
-            print(f"simjax: {n_points} point(s) split over {d} of the "
-                  f"{devices} requested device(s)", file=sys.stderr)
-        if d > 1:
-            from repro.distributed.sharding import device_mesh
-            pt_sharding = NamedSharding(device_mesh(d, "points"), P("points"))
+    with runs.run(points=n_points, devices=devices) as info:
+        with runs.span("prep.rate_matrix"):
+            arr_np = np.asarray(rate_matrix(trace, dt))
+        n_ticks, f = arr_np.shape
+        chunk_ticks = max(1, min(chunk_ticks, n_ticks))
+        info.update(functions=f, ticks=n_ticks, chunk_ticks=chunk_ticks)
+        gq, alive_tab, tail_tab = gap_statistics(trace)
 
-    lam_eff = jnp.broadcast_to(jnp.asarray(lam_np, jnp.float32),
-                               (n_points, f))
-    gaps = jnp.asarray(gq, jnp.float32)
-    gap_tab = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
-                           (alive_tab, tail_tab))
-    edges_j = jnp.asarray(edges)
-    weights_j = None if weights_np is None \
-        else jnp.asarray(weights_np, jnp.float32)
+        with runs.span("prep.device"):
+            dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(
+                trace, policy, sim, dt)
+            billed_w = _billed_weights(trace, billing)
+            dur_median = np.asarray(trace.profile.dur_median)
+            dur_sigma = np.asarray(trace.profile.dur_sigma)
+            weights_np = getattr(trace, "weights", None)
+            prov_ticks = max(1, int(round(provision_s / dt)))
+            edges = _delay_edges(nbins)
+            warm_tick = int(n_ticks * warmup_frac)
+            lam_np = arr_np.mean(axis=0) / dt
 
-    def init_point(fl):
-        init_nodes = fl[0] if has_fleet else jnp.asarray(float(num_nodes))
-        return _init_state(f, cold_ticks, wbuf, prov_ticks, init_nodes)
+            fn_mesh = pt_sharding = None
+            f_orig = f
+            if devices > 0 and not has_fleet:
+                from repro.distributed.sharding import device_mesh
+                fn_mesh = device_mesh(devices, "functions")
+                pad = (-f) % devices
+                if pad:
+                    # inert padding functions: zero arrivals -> zero
+                    # instances, creations, memory and histogram mass
+                    # (trimmed below anyway)
+                    arr_np = np.concatenate(
+                        [arr_np, np.zeros((n_ticks, pad), arr_np.dtype)],
+                        axis=1)
+                    dur = jnp.concatenate([dur, jnp.ones(pad, dur.dtype)])
+                    mem = jnp.concatenate([mem, jnp.zeros(pad, mem.dtype)])
+                    billed_w = jnp.concatenate(
+                        [billed_w, jnp.zeros(pad, billed_w.dtype)])
+                    lam_np = np.concatenate([lam_np, np.zeros(pad)])
+                    gq = np.concatenate([gq, np.full(pad, trace.duration_s)])
+                    # never-observed-gap convention: alive = ka, tail = 1
+                    from repro.core.trace import KA_GRID
+                    alive_tab = np.concatenate(
+                        [alive_tab,
+                         np.broadcast_to(KA_GRID, (pad, len(KA_GRID)))])
+                    tail_tab = np.concatenate(
+                        [tail_tab, np.ones((pad, len(KA_GRID)))])
+                    if weights_np is not None:
+                        weights_np = np.concatenate(
+                            [weights_np, np.zeros(pad)])
+                    f += pad
+            elif devices > 0 and has_fleet:
+                d = _largest_divisor(n_points, devices)
+                if devices > 1 and d < devices:
+                    print(f"simjax: {n_points} point(s) split over {d} of "
+                          f"the {devices} requested device(s)",
+                          file=sys.stderr)
+                if d > 1:
+                    from repro.distributed.sharding import device_mesh
+                    pt_sharding = NamedSharding(device_mesh(d, "points"),
+                                                P("points"))
 
-    state = jax.vmap(init_point)(jnp.asarray(fleets, jnp.float32))
-    pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), pols)
-    fleets_j = jnp.asarray(fleets, jnp.float32)
-    if pt_sharding is not None:
-        # point-axis sharding: place the vmapped batch over the mesh and
-        # let GSPMD partition the existing compiled dispatch — frontier
-        # candidates run as grid-points x devices in one call
-        state = jax.device_put(state, pt_sharding)
-        lam_eff = jax.device_put(lam_eff, pt_sharding)
-        pols_j = jax.device_put(pols_j, pt_sharding)
-        fleets_j = jax.device_put(fleets_j, pt_sharding)
+            lam_eff = jnp.broadcast_to(jnp.asarray(lam_np, jnp.float32),
+                                       (n_points, f))
+            gaps = jnp.asarray(gq, jnp.float32)
+            gap_tab = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
+                                   (alive_tab, tail_tab))
+            edges_j = jnp.asarray(edges)
+            weights_j = None if weights_np is None \
+                else jnp.asarray(weights_np, jnp.float32)
 
-    hist = np.zeros((n_points, f, nbins))
-    arrtot = np.zeros((n_points, f))
-    sums = np.zeros((n_points, len(_ACC_NAMES)))
-    n = np.zeros(n_points)
-    telemetry = int(telemetry)
-    tser = np.zeros((n_points, max(telemetry, 1), len(TELEM_SERIES)))
-    tcnt = np.zeros((n_points, max(telemetry, 1)))
-    tattr = np.zeros((n_points, len(TELEM_ATTR)))
-    for t0 in range(0, n_ticks, chunk_ticks):
-        a = arr_np[t0:t0 + chunk_ticks]
-        if a.shape[0] < chunk_ticks:        # pad the tail chunk; the padded
-            a = np.concatenate(             # ticks are masked out of the stats
-                [a, np.zeros((chunk_ticks - a.shape[0], f), a.dtype)])
-        if fn_mesh is not None:
-            state, out = _chunk_batch_fnshard(
-                state, jnp.asarray(a), lam_eff, gaps, gap_tab, dur, mem,
-                billed_w, pols_j, fleets_j, edges_j,
-                jnp.asarray(t0, jnp.int32), weights_j, mesh=fn_mesh,
-                cpu_consts=cpu_consts, static_nodes=float(num_nodes),
-                warm_tick=warm_tick, total_ticks=n_ticks,
-                family=policy.family, dt=dt, cold_ticks=cold_ticks,
-                wbuf=wbuf, prov_ticks=prov_ticks, telem_slots=telemetry)
-        else:
-            state, out = _chunk_batch(
-                state, jnp.asarray(a), lam_eff, gaps, gap_tab, dur, mem,
-                billed_w, pols_j, fleets_j,
-                cpu_consts, float(num_nodes), edges_j,
-                jnp.asarray(t0, jnp.int32), warm_tick=warm_tick,
-                total_ticks=n_ticks, family=policy.family, dt=dt,
-                cold_ticks=cold_ticks, wbuf=wbuf, prov_ticks=prov_ticks,
-                has_fleet=has_fleet, telem_slots=telemetry,
-                weights=weights_j)
-        hist += np.asarray(out[0])
-        arrtot += np.asarray(out[1])
-        sums += np.asarray(out[2])
-        n += np.asarray(out[3])
-        if telemetry:
-            tser += np.asarray(out[4])
-            tcnt += np.asarray(out[5])
-            tattr += np.asarray(out[6])
-    iid = get_family(policy.family).synchronous_tail
-    fw = None if weights_np is None else np.asarray(weights_np)[:f_orig]
-    rows = [_acc_summary(hist[i, :f_orig], arrtot[i, :f_orig], sums[i], n[i],
-                         edges, dur_median, dur_sigma, sim.warm_latency_s,
-                         dt, iid_tail=iid, fn_weights=fw)
-            for i in range(n_points)]
+            def init_point(fl):
+                init_nodes = fl[0] if has_fleet \
+                    else jnp.asarray(float(num_nodes))
+                return _init_state(f, cold_ticks, wbuf, prov_ticks,
+                                   init_nodes)
+
+            state = jax.vmap(init_point)(jnp.asarray(fleets, jnp.float32))
+            pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
+                                  pols)
+            fleets_j = jnp.asarray(fleets, jnp.float32)
+            if pt_sharding is not None:
+                # point-axis sharding: place the vmapped batch over the mesh
+                # and let GSPMD partition the existing compiled dispatch —
+                # frontier candidates run as grid-points x devices in one
+                # call
+                state = jax.device_put(state, pt_sharding)
+                lam_eff = jax.device_put(lam_eff, pt_sharding)
+                pols_j = jax.device_put(pols_j, pt_sharding)
+                fleets_j = jax.device_put(fleets_j, pt_sharding)
+
+        hist = np.zeros((n_points, f, nbins))
+        arrtot = np.zeros((n_points, f))
+        sums = np.zeros((n_points, len(_ACC_NAMES)))
+        n = np.zeros(n_points)
+        telemetry = int(telemetry)
+        tser = np.zeros((n_points, max(telemetry, 1), len(TELEM_SERIES)))
+        tcnt = np.zeros((n_points, max(telemetry, 1)))
+        tattr = np.zeros((n_points, len(TELEM_ATTR)))
+        for index, t0 in enumerate(range(0, n_ticks, chunk_ticks)):
+            with runs.span("chunk", index=index):
+                a = _upload_chunks(arr_np[t0:t0 + chunk_ticks],
+                                   ticks=chunk_ticks)[0]
+                with runs.span("chunk.dispatch"):
+                    if fn_mesh is not None:
+                        state, out = _chunk_batch_fnshard(
+                            state, a, lam_eff, gaps, gap_tab, dur, mem,
+                            billed_w, pols_j, fleets_j, edges_j,
+                            jnp.asarray(t0, jnp.int32), weights_j,
+                            mesh=fn_mesh, cpu_consts=cpu_consts,
+                            static_nodes=float(num_nodes),
+                            warm_tick=warm_tick, total_ticks=n_ticks,
+                            family=policy.family, dt=dt,
+                            cold_ticks=cold_ticks, wbuf=wbuf,
+                            prov_ticks=prov_ticks, telem_slots=telemetry)
+                    else:
+                        state, out = _chunk_batch(
+                            state, a, lam_eff, gaps, gap_tab, dur, mem,
+                            billed_w, pols_j, fleets_j,
+                            cpu_consts, float(num_nodes), edges_j,
+                            jnp.asarray(t0, jnp.int32), warm_tick=warm_tick,
+                            total_ticks=n_ticks, family=policy.family,
+                            dt=dt, cold_ticks=cold_ticks, wbuf=wbuf,
+                            prov_ticks=prov_ticks, has_fleet=has_fleet,
+                            telem_slots=telemetry, weights=weights_j)
+                out = _read_back(out)
+                with runs.span("chunk.accumulate"):
+                    hist += out[0]
+                    arrtot += out[1]
+                    sums += out[2]
+                    n += out[3]
+                    if telemetry:
+                        tser += out[4]
+                        tcnt += out[5]
+                        tattr += out[6]
+        iid = get_family(policy.family).synchronous_tail
+        fw = None if weights_np is None else np.asarray(weights_np)[:f_orig]
+        rows = []
+        for i in range(n_points):
+            with runs.span("summary", point=i):
+                rows.append(_acc_summary(
+                    hist[i, :f_orig], arrtot[i, :f_orig], sums[i], n[i],
+                    edges, dur_median, dur_sigma, sim.warm_latency_s, dt,
+                    iid_tail=iid, fn_weights=fw))
     if telemetry:
         for i, row in enumerate(rows):
             row["telemetry"] = assemble_telemetry(tser[i], tcnt[i], tattr[i],
                                                   n_ticks, dt)
     return rows
+
+
+def _upload_chunks(*chunks, ticks: int) -> list:
+    """Send one time chunk's per-tick inputs to the device, the tail chunk
+    padded with zeros to ``ticks`` (the padded ticks are masked out of the
+    statistics); counts their bytes as ``upload_bytes``."""
+    with runs.span("chunk.upload"):
+        out = []
+        for c in chunks:
+            if c.shape[0] < ticks:
+                c = np.concatenate(
+                    [c, np.zeros((ticks - c.shape[0],) + c.shape[1:],
+                                 c.dtype)])
+            runs.count("upload_bytes", c.nbytes)
+            out.append(jnp.asarray(c))
+        return out
+
+
+def _read_back(out) -> list:
+    """Wait for one chunk's statistics, then copy them to the host; counts
+    their bytes as ``readback_bytes``."""
+    with runs.span("chunk.wait"):
+        jax.block_until_ready(out)
+    with runs.span("chunk.readback"):
+        host = [np.asarray(x) for x in out]
+        runs.count("readback_bytes", sum(x.nbytes for x in host))
+    return host
 
 
 def simulate_chunked(trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),

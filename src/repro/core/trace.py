@@ -23,6 +23,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs.runs import span
+
 
 @dataclasses.dataclass(frozen=True)
 class TraceConfig:
@@ -326,18 +328,27 @@ def gap_statistics(trace, q: float = 0.99,
     P(gap > ka) = e^{-lam ka}.  Zero-rate functions report the trace
     duration / pure idle-timer limits, matching the empirical convention
     for functions with fewer than two arrivals."""
-    if isinstance(trace, RateTrace):
-        lam = np.asarray(trace.counts, np.float64).mean(axis=0) / trace.tick_s
-        f, k = len(lam), len(grid)
-        pos = lam > 0
-        gq = np.full(f, trace.duration_s, np.float64)
-        gq[pos] = np.minimum(-np.log1p(-q) / lam[pos], trace.duration_s)
-        alive = np.broadcast_to(grid, (f, k)).copy()
-        tail = np.ones((f, k))
-        lg = lam[pos, None] * grid[None, :]
-        alive[pos] = -np.expm1(-lg) / lam[pos, None]
-        tail[pos] = np.exp(-lg)
-        return gq, alive, tail
-    per_fn = list(_function_gaps(trace))
-    return (gap_quantile(trace, q, gaps=per_fn),
-            *gap_tables(trace, grid, gaps=per_fn))
+    with span("prep.gap_statistics"):
+        if isinstance(trace, RateTrace):
+            return _poisson_gap_statistics(trace, q, grid)
+        with span("prep.gap_extract"):
+            per_fn = list(_function_gaps(trace))
+        with span("prep.gap_quantile"):
+            gq = gap_quantile(trace, q, gaps=per_fn)
+        with span("prep.gap_tables"):
+            return (gq, *gap_tables(trace, grid, gaps=per_fn))
+
+
+def _poisson_gap_statistics(trace: RateTrace, q: float, grid: np.ndarray):
+    """``gap_statistics`` of a RateTrace: the analytic Poisson forms."""
+    lam = np.asarray(trace.counts, np.float64).mean(axis=0) / trace.tick_s
+    f, k = len(lam), len(grid)
+    pos = lam > 0
+    gq = np.full(f, trace.duration_s, np.float64)
+    gq[pos] = np.minimum(-np.log1p(-q) / lam[pos], trace.duration_s)
+    alive = np.broadcast_to(grid, (f, k)).copy()
+    tail = np.ones((f, k))
+    lg = lam[pos, None] * grid[None, :]
+    alive[pos] = -np.expm1(-lg) / lam[pos, None]
+    tail[pos] = np.exp(-lg)
+    return gq, alive, tail
