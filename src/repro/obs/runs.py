@@ -5,10 +5,10 @@ Every call of ``simjax._chunked_summaries`` or
 of timed phases (``span``: trace preparation, each time chunk's upload,
 dispatch, wait, read-back and accumulation, each point's summary solve)
 and of counted quantities (``count``: bytes uploaded and read back, step
-programs traced).  Spans are kept in a ``repro.obs.spans.SpanRecorder``
-with parent ids, so ``validate`` and ``chrome_trace`` apply; times are
-``time.perf_counter()`` seconds.  The last ``MAX_RUNS`` finished runs
-stay readable through ``last_runs``.
+programs traced, rolling windows of the gap quantile).  Spans are kept in
+a ``repro.obs.spans.SpanRecorder`` with parent ids, so ``validate`` and
+``chrome_trace`` apply; times are ``time.perf_counter()`` seconds.  The
+last ``MAX_RUNS`` finished runs stay readable through ``last_runs``.
 
 Each span also enters a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>``, so a profiler trace (``jax.profiler.trace``, read in

@@ -95,6 +95,27 @@ def test_byte_counters_follow_the_shapes(trace):
     assert rec.counters["readback_bytes"] == chunks * _chunk_bytes(1, F)
 
 
+def _rolling_windows(trace, window=256, stride=16):
+    """Rolling windows the per-window ``gap_quantile`` loop evaluates:
+    those ending in the measurement half (else the last one) of each
+    function with more than ``window`` gaps."""
+    n = 0
+    for i in range(trace.num_functions):
+        t = np.sort(trace.t[trace.fn == i])
+        g = np.diff(t)
+        if len(g) <= window:
+            continue
+        ends = np.arange(window, len(g) + 1,
+                         max(1, min(stride, (len(g) - window) // 8 + 1)))
+        n += max(1, int((t[1:][ends - 1] >= trace.duration_s / 2).sum()))
+    return n
+
+
+def test_gap_windows_counts_the_rolling_windows(trace):
+    _, rec = _one_run(lambda: _replay(trace))
+    assert rec.counters["gap_windows"] == _rolling_windows(trace) > 0
+
+
 def test_telemetry_leaves_are_read_back_and_counted(trace):
     _, rec = _one_run(lambda: _replay(trace, spec=RunSpec(telemetry=4)))
     from repro.obs.telemetry import TELEM_ATTR, TELEM_SERIES
