@@ -55,7 +55,7 @@ def main(argv=None) -> int:
         if seed in args.seeds:
             t = time.perf_counter()
             rows = Job(cell.config, cell.traffic, cell.chips, data,
-                       NoSpans())()
+                       NoSpans(), cell.root)()
             rec["job_s"] = time.perf_counter() - t
             rec["program"] = check.job_gaps(rows, ref, points, n, keys)
         if seed in args.control_seeds:

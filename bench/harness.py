@@ -14,14 +14,20 @@ the end of the last one.  With ``--trace 1`` the profiler records the
 first jobs of the window (at most ``TRACE_JOBS``) with the benchmark's
 host spans, and the run reports the cell's per-layer metrics instead of
 the end-to-end ones.  After the window the device's peak memory is read,
-and the plain reference (``bench.reference``) recomputes the checked rows
-from the same workload; ``bench.check`` compares every window job's rows
-with it.
+and the plain reference of the configuration's policy family recomputes
+the checked rows from the same workload; ``bench.check`` compares every
+window job's rows with it.
 
-Everything that belongs to one configuration, traffic mix, per-layer
-metric or cell is found by name: ``bench/configs/<config>.json`` (the
-``file`` of the configuration), ``bench/traffic/<traffic>.json``,
-``bench/metrics/<metric>.py`` and ``bench/limits/<cell>.json``.
+Everything that belongs to one configuration, traffic mix, job entry,
+policy family, per-layer metric or cell is found by name:
+``bench/configs/<config>.json`` (the ``file`` of the configuration),
+``bench/traffic/<traffic>.json``, ``bench/entries/<entry>.py`` (the
+traffic's ``entry``: the call the window drives, ``bench.jobs``),
+``bench/references/<family>.py`` (the configuration's ``policy.family``:
+its tick and summary, on the shared parts of ``bench.reference``),
+``bench/metrics/<metric>.py`` and ``bench/limits/<cell>.json``.  Modules
+are looked up under the cell's ``root`` (``bench.lookup``), so a new cell
+brings its parts as new files.
 """
 
 from __future__ import annotations
@@ -30,7 +36,6 @@ import argparse
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -39,11 +44,11 @@ import tempfile
 import time
 from pathlib import Path
 
-from bench import check, gen, reference, trace_reduce
+from bench import check, gen, lookup, reference, trace_reduce
 from bench.jobs import Job
 from bench.spans import NoSpans, Spans
 
-ROOT = Path(__file__).resolve().parents[1]
+ROOT = lookup.ROOT
 TRACE_JOBS = 2
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -60,6 +65,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    root: Path = ROOT
 
 
 def load_json(path: Path):
@@ -85,16 +91,12 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                                   / f"{w['traffic']}.json"),
                 limits=limits["limits"],
                 end_to_end=[m for m in bm["end_to_end"] if _applies(m, name)],
-                per_layer=[m for m in bm["per_layer"] if _applies(m, name)])
+                per_layer=[m for m in bm["per_layer"] if _applies(m, name)],
+                root=root)
 
 
 def load_reader(metric: str, root: Path = ROOT):
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return lookup.load("metrics", metric, root).read
 
 
 class CompileLog:
@@ -124,13 +126,17 @@ def _finite(x):
 
 def reference_rows(cell: Cell, data, points: list[int],
                    dtype: str = "float32"):
+    """The reference's rows of the checked ``points``: the tick and summary
+    of the configuration's policy family, on the elastic fleet where the
+    traffic's entry declares it; ``dtype="bfloat16"`` gives the control."""
+    family = lookup.load("references", cell.config["policy"]["family"],
+                         cell.root)
+    fleet = lookup.load("entries", cell.traffic["entry"],
+                        cell.root).ELASTIC_FLEET
     wl = reference.prepare(cell.config, data)
-    pts = [cell.traffic.get("points", [{}])[i] for i in points]
-    pts = [{"keepalive_s": cell.config["policy"]["keepalive_s"], **p}
-           for p in pts]
-    return reference.simulate(wl, cell.config, pts,
-                              fleet=cell.traffic["entry"] == "sweep",
-                              dtype=dtype)
+    pts = [{**cell.config["policy"], **cell.traffic.get("points", [{}])[i]}
+           for i in points]
+    return family.simulate(wl, cell.config, pts, fleet=fleet, dtype=dtype)
 
 
 def run(argv=None, *, t0: float | None = None, require_chip: bool = True,
@@ -166,7 +172,8 @@ def run(argv=None, *, t0: float | None = None, require_chip: bool = True,
 
     data = gen.generate(cell.config, args.seed)
     spans = Spans() if args.trace else NoSpans()
-    job = Job(cell.config, cell.traffic, cell.chips, data, spans)
+    job = Job(cell.config, cell.traffic, cell.chips, data, spans,
+              cell.root)
     n_points = len(job.points)
     points = check.checked_points(n_points,
                                   cell.traffic.get("check_points", n_points),
@@ -237,7 +244,7 @@ def run(argv=None, *, t0: float | None = None, require_chip: bool = True,
         obs = Observation(span_jobs=spans.jobs, trace=red)
         metrics = {}
         for m in cell.per_layer:
-            v = load_reader(m["name"])(obs)
+            v = load_reader(m["name"], cell.root)(obs)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         if red is not None:

@@ -1,14 +1,13 @@
 """The calls a cell's window drives: one whole job of a traffic mix.
 
-A traffic file names its ``entry``:
-
-* ``replay``: ``repro.core.simjax.simulate_chunked`` over the deployment's
-  whole horizon, as ``runner._run_simjax`` calls it; a deployment with a
-  ``cluster_below_rps`` first runs ``repro.scenarios.cluster
-  .cluster_functions`` inside the job.  ``shard: functions`` shards the
-  function axis over the cell's chips (``RunSpec(devices=chips)``).
-* ``sweep``: ``repro.opt.search.evaluate_points`` over the traffic's
-  ``points`` on the deployment's elastic fleet.
+A traffic file names its ``entry``, a module ``bench/entries/<entry>.py``
+(found by ``bench.lookup``).  The module declares the ``SHARDS`` it
+accepts, whether its reference runs on the deployment's elastic fleet
+(``ELASTIC_FLEET``), and ``run(job, trace)``, which runs one job on the
+program's trace and returns the summary rows.  A shard other than
+``none`` gives the job the cell's chips (``Job.devices``).  A deployment
+with a ``cluster_below_rps`` first runs ``repro.scenarios.cluster
+.cluster_functions`` inside the job, whatever the entry.
 
 A job starts from a fresh program trace object around the benchmark's
 read-only workload arrays and ends with the summary rows on the host.
@@ -17,8 +16,9 @@ read-only workload arrays and ends with the summary rows on the host.
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 
-from bench import gen
+from bench import gen, lookup
 
 
 def program_trace(data):
@@ -35,7 +35,7 @@ class Job:
     """One traffic mix bound to one deployment, its workload and chips."""
 
     def __init__(self, config: dict, traffic: dict, chips: int, data,
-                 spans):
+                 spans, root: Path = lookup.ROOT):
         from repro.core.eventsim import SimConfig
         from repro.core.simjax import JaxFleet, JaxPolicy
         self.config, self.traffic, self.data = config, traffic, data
@@ -44,12 +44,12 @@ class Job:
         self.sim = SimConfig(tick_s=self.tick, **config["sim"])
         self.policy = JaxPolicy(**config["policy"])
         self.fleet = JaxFleet(**config["fleet"])
-        self.devices = chips if traffic["shard"] == "functions" else 0
-        if traffic["shard"] not in ("none", "functions"):
-            raise ValueError(f"unknown shard {traffic['shard']!r}")
+        self.entry = lookup.load("entries", traffic["entry"], root)
+        if traffic["shard"] not in self.entry.SHARDS:
+            raise ValueError(f"entry {traffic['entry']!r} takes a shard in "
+                             f"{self.entry.SHARDS}, not {traffic['shard']!r}")
+        self.devices = 0 if traffic["shard"] == "none" else chips
         self.points = traffic.get("points", [{}])
-        self.entry = {"replay": self._replay,
-                      "sweep": self._sweep}[traffic["entry"]]
         self.simulated_functions = None
 
     @property
@@ -68,23 +68,4 @@ class Job:
                         bins_per_octave=self.config["cluster_bins_per_octave"],
                         tick_s=self.tick)
             self.simulated_functions = trace.num_functions
-            return self.entry(trace)
-
-    def _replay(self, trace) -> list[dict]:
-        from repro.core.runspec import RunSpec
-        from repro.core.simjax import simulate_chunked
-        c = self.config
-        return [simulate_chunked(
-            trace, self.policy, self.sim, dt=self.tick,
-            num_nodes=c["num_nodes"], chunk_ticks=c["chunk_ticks"],
-            warmup_frac=c["warmup_frac"], nbins=c["nbins"],
-            spec=RunSpec(devices=self.devices))]
-
-    def _sweep(self, trace) -> list[dict]:
-        from repro.opt.search import evaluate_points
-        c = self.config
-        return evaluate_points(trace, self.policy, self.fleet, self.points,
-                               sim=self.sim, dt=self.tick,
-                               warmup_frac=c["warmup_frac"],
-                               chunk_ticks=c["chunk_ticks"],
-                               devices=self.devices)
+            return self.entry.run(self, trace)

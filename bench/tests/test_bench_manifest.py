@@ -7,7 +7,7 @@ import re
 
 import pytest
 
-from bench import gen, harness
+from bench import gen, harness, lookup
 from bench.jobs import Job
 from bench.spans import NoSpans
 from bench.tests.util import RATES, SHARDED, tiny_cell
@@ -45,12 +45,16 @@ def test_end_to_end_bounds_and_sources():
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_files_exist_and_load(name):
+    """The cell's entry module exists and takes its shard, and every limit
+    is a row that the cell's reference returns (run on the shrunk cell)."""
     cell = harness.load_cell(name)
     assert cell.chips in (1, 4)
-    assert cell.traffic["entry"] in ("replay", "sweep")
-    assert set(cell.limits) <= set(("slowdown_geomean_p99",
-                                    "normalized_memory", "creation_rate",
-                                    "cpu_overhead", "total_cost"))
+    entry = lookup.load("entries", cell.traffic["entry"])
+    assert cell.traffic["shard"] in entry.SHARDS
+    assert isinstance(entry.ELASTIC_FLEET, bool) and callable(entry.run)
+    tiny = tiny_cell(name)
+    rows = harness.reference_rows(tiny, gen.generate(tiny.config, 3), [0])
+    assert cell.limits and set(cell.limits) <= set(rows[0])
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert cell.per_layer
     for m in cell.per_layer:
