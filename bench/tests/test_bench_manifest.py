@@ -7,10 +7,11 @@ import re
 
 import pytest
 
-from bench import gen, harness, lookup
+from bench import gen, harness
 from bench.jobs import Job
 from bench.spans import NoSpans
-from bench.tests.util import RATES, SHARDED, tiny_cell
+from bench.tests.util import (RATES, SHARDED, assert_cell_files_load,
+                              assert_config_states_its_cuts, tiny_cell)
 
 BM = harness.load_json(harness.ROOT / "BENCHMARK.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -45,30 +46,12 @@ def test_end_to_end_bounds_and_sources():
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_files_exist_and_load(name):
-    """The cell's entry module exists and takes its shard, and every limit
-    is a row that the cell's reference returns (run on the shrunk cell)."""
-    cell = harness.load_cell(name)
-    assert cell.chips in (1, 4)
-    entry = lookup.load("entries", cell.traffic["entry"])
-    assert cell.traffic["shard"] in entry.SHARDS
-    assert isinstance(entry.ELASTIC_FLEET, bool) and callable(entry.run)
-    tiny = tiny_cell(name)
-    rows = harness.reference_rows(tiny, gen.generate(tiny.config, 3), [0])
-    assert cell.limits and set(cell.limits) <= set(rows[0])
-    assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
-    assert cell.per_layer
-    for m in cell.per_layer:
-        assert callable(harness.load_reader(m["name"]))
+    assert_cell_files_load(name)
 
 
 @pytest.mark.parametrize("conf", BM["configs"], ids=lambda c: c["name"])
 def test_config_file_states_its_cuts(conf):
-    cfg = harness.load_json(harness.ROOT / conf["file"])
-    assert cfg["name"] == conf["name"]
-    assert sorted(cfg["reduced"]) == sorted(conf["reduced"])
-    for key in conf["reduced"]:
-        assert key in cfg["published"]
-    assert cfg["precision"] == "float32"
+    assert_config_states_its_cuts(conf)
 
 
 @pytest.mark.parametrize("name", ["azure2k.replay", RATES, "azure2k.sweep16",
