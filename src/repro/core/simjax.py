@@ -777,13 +777,25 @@ def _slowdown_geomean_np(hist, arrtot, edges, dur_median, dur_sigma, warm,
         else np.full(len(n), q)
     lo = np.full(h.shape[0], 1.0)
     hi = np.full(h.shape[0], 1.0 + w.max() / _DUR_FLOOR + 1.0)
+    # The survival function runs only on occupied cells (~1% of them on
+    # the Fig. 9 replay); an empty cell adds p * 0.0 = +0.0 to its row,
+    # exactly what p = 0 times a finite sf added, so the dense row sums
+    # below see the same values in the same order: bit for bit.
+    live = np.flatnonzero(p)
+    row, b = np.divmod(live, p.shape[1])
+    w_live, med_live, sig_live = w[0, b], log_med[row, 0], sig[row, 0]
+    sf = np.zeros(p.shape)
+    sf_flat = sf.reshape(-1)
+    runs.count("solve_cells", p.size)
+    runs.count("solve_live_cells", live.size)
     for _ in range(60):
         mid = 0.5 * (lo + hi)
-        x = w / np.maximum(mid - 1.0, 1e-12)[:, None]
-        sf = np.where(x <= _DUR_FLOOR, 1.0,
-                      np.where(x >= _DUR_CAP, 0.0,
-                               1.0 - _phi((np.log(np.maximum(x, 1e-300))
-                                           - log_med) / sig)))
+        x = w_live / np.maximum(mid - 1.0, 1e-12)[row]
+        sf_flat[live] = np.where(
+            x <= _DUR_FLOOR, 1.0,
+            np.where(x >= _DUR_CAP, 0.0,
+                     1.0 - _phi((np.log(np.maximum(x, 1e-300))
+                                 - med_live) / sig_live)))
         ok = (p * sf).sum(axis=1) >= q_eff
         hi = np.where(ok, mid, hi)
         lo = np.where(ok, lo, mid)

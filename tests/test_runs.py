@@ -116,6 +116,20 @@ def test_gap_windows_counts_the_rolling_windows(trace):
     assert rec.counters["gap_windows"] == _rolling_windows(trace) > 0
 
 
+def test_solve_counts_its_cells_and_rows_match_the_dense_solve(
+        trace, monkeypatch):
+    """The host solve counts the histogram cells of its kept functions and
+    the occupied ones it evaluates; the row is bit for bit the one the
+    dense evaluation of every cell gives."""
+    import repro.core.simjax as simjax
+    from test_summary_solve import dense_geomean_np
+    row, rec = _one_run(lambda: _replay(trace))
+    cells, live = rec.counters["solve_cells"], rec.counters["solve_live_cells"]
+    assert 0 < live <= cells <= F * 256 and cells % 256 == 0
+    monkeypatch.setattr(simjax, "_slowdown_geomean_np", dense_geomean_np)
+    assert _replay(trace) == row
+
+
 def test_telemetry_leaves_are_read_back_and_counted(trace):
     _, rec = _one_run(lambda: _replay(trace, spec=RunSpec(telemetry=4)))
     from repro.obs.telemetry import TELEM_ATTR, TELEM_SERIES
