@@ -16,7 +16,7 @@ from repro.core.cluster import Cluster
 from repro.core.eventsim import EventSim, SimConfig
 from repro.core.metrics import compute
 from repro.core.policies import AsyncConcurrencyPolicy
-from repro.core.simjax import JaxFleet, JaxPolicy, simulate, summarize
+from repro.core.simjax import JaxFleet, JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, synthesize
 from repro.fleet import (NodeFleet, NodeType, UtilizationFleetPolicy,
                          cost_from_sim)
@@ -51,12 +51,12 @@ def main():
           f"(churn ${bill.churn_cost:.3f}, idle ${bill.idle_cost:.3f})")
 
     # -- 2. vectorized simulator, fleet in the scan carry --------------------
-    s = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
-                           fleet=JaxFleet(node_memory_mb=NODE.memory_mb,
-                                          provision_s=NODE.provision_s,
-                                          min_nodes=1, max_nodes=32,
-                                          util_target=0.7, warm_frac=0.25,
-                                          cooldown_s=120.0)))
+    s = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                         fleet=JaxFleet(node_memory_mb=NODE.memory_mb,
+                                        provision_s=NODE.provision_s,
+                                        min_nodes=1, max_nodes=32,
+                                        util_target=0.7, warm_frac=0.25,
+                                        cooldown_s=120.0))
     print(f"\nsimjax fleet: nodes_mean={s['nodes_mean']:.1f} "
           f"slowdown_p99={s['slowdown_geomean_p99']:.2f} "
           f"(oracle/fluid node ratio "

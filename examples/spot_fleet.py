@@ -19,7 +19,7 @@ from repro.core.cluster import Cluster
 from repro.core.eventsim import EventSim, SimConfig
 from repro.core.metrics import compute
 from repro.core.policies import SpotAwarePolicy
-from repro.core.simjax import JaxFleet, JaxPolicy, simulate, summarize
+from repro.core.simjax import JaxFleet, JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, synthesize
 from repro.fleet import (NodeType, PriceBook, SpotMarket, SpotNodeFleet,
                          UtilizationFleetPolicy, cost_from_sim, get_tier)
@@ -66,10 +66,10 @@ def main():
     jf = JaxFleet(node_memory_mb=NODE.memory_mb, provision_s=NODE.provision_s,
                   min_nodes=1, max_nodes=32, util_target=0.7, warm_frac=0.25,
                   cooldown_s=120.0, reclaim_notice_s=SPOT.reclaim_notice_s)
-    s = summarize(simulate(trace, JaxPolicy(
+    s = simulate_chunked(trace, JaxPolicy(
         family="spot_aware", keepalive_s=600,
         extra={"spot_fraction": 0.6,
-               "hazard_per_hour": SPOT.hazard_per_hour}), fleet=jf))
+               "hazard_per_hour": SPOT.hazard_per_hour}), fleet=jf)
     print(f"\nsimjax spot fleet: nodes_mean={s['nodes_mean']:.1f} "
           f"(spot {s['spot_nodes_mean']:.1f}) "
           f"slowdown_p99={s['slowdown_geomean_p99']:.2f} "
@@ -81,10 +81,10 @@ def main():
           f"{'spot nodes':>10s}")
     t0 = time.time()
     for sf in (0.0, 0.3, 0.6, 0.9):
-        s = summarize(simulate(trace, JaxPolicy(
+        s = simulate_chunked(trace, JaxPolicy(
             family="spot_aware", keepalive_s=600,
             extra={"spot_fraction": sf,
-                   "hazard_per_hour": SPOT.hazard_per_hour}), fleet=jf))
+                   "hazard_per_hour": SPOT.hazard_per_hour}), fleet=jf)
         spot_s = s["spot_node_seconds"]
         od_rate = NODE.price_per_hour
         cost = ((s["node_seconds"] - spot_s) * od_rate

@@ -37,11 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.simjax import (_ACC_NAMES, _PFLEET, _acc_summary,
-                               _billed_weights, _delay_edges, _init_state,
-                               _make_step, _prep_static, _read_back,
-                               _upload_chunks, stack_params)
-from repro.core.policy_api import get_family
+from repro.core.simjax import (_ACC_NAMES, _PFLEET, _delay_bin,
+                               _drive_chunks, _init_state, _make_step,
+                               _point_rows, _run_statics, stack_params)
 from repro.core.trace import gap_statistics, rate_matrix
 from repro.obs import runs
 
@@ -169,8 +167,7 @@ def _cells_chunk_impl(state, arr_chunk, floor_chunk, lam0, gaps, alive_tab,
         delay, arr, arr_delayed = ys[0], ys[1], ys[2]
         wmask = m * alive[:, None]
         with jax.named_scope("delay_histogram"):
-            b = jnp.clip(jnp.searchsorted(edges, delay.reshape(-1),
-                                          side="right"), 0, nbins - 1)
+            b = _delay_bin(edges, delay.reshape(-1), nbins)
             hist = hist.at[rows_flat, b].add(
                 (arr_delayed * wmask).reshape(-1))
             hist = hist.at[:, 0].add(((arr - arr_delayed) * wmask).sum(0))
@@ -219,15 +216,10 @@ def cells_chunked_summaries(traces, topo: CellTopology, policy, pols,
                             chunk_ticks: int, warmup_frac: float = 0.5,
                             nbins: int = 256, billing=None,
                             detail: Optional[dict] = None) -> list:
-    """Run a batch of policy/fleet points through the C-cell chunked scan
-    and return one ``summarize``-style row per point (the multi-cell twin
-    of ``simjax._chunked_summaries``; same metric keys, cross-cell sums).
-
-    ``traces`` is the per-cell partition from ``build_cell_traces`` (one
-    ``Trace`` per cell over the SHARED function id space).  When ``detail``
-    is a dict it receives ``cell_rows`` — point 0's per-cell attribution
-    partials (node-seconds, churn CPU, completions per cell).
-    """
+    """The multi-cell twin of ``simjax._chunked_summaries`` through the same
+    host path: per point, one row summed over the cells' traces (from
+    ``build_cell_traces``, one shared function id space); a ``detail`` dict
+    receives ``cell_rows``, point 0's per-cell attribution partials."""
     c_n = topo.cell_count
     if len(traces) != c_n:
         raise ValueError(f"got {len(traces)} cell traces for a "
@@ -245,96 +237,57 @@ def cells_chunked_summaries(traces, topo: CellTopology, policy, pols,
         gq_l, at_l, tt_l = zip(*(gap_statistics(tr) for tr in traces))
 
         with runs.span("prep.device"):
-            duration_s = traces[0].duration_s
-            dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(
-                traces[0], policy, sim, dt)
-            billed_w = _billed_weights(traces[0], billing)  # profile-wide
-            dur_median = np.asarray(traces[0].profile.dur_median)
-            dur_sigma = np.asarray(traces[0].profile.dur_sigma)
-            prov_ticks = max(1, int(round(provision_s / dt)))
-            edges = _delay_edges(nbins)
-            edges_j = jnp.asarray(edges)
-            warm_tick = int(n_ticks * warmup_frac)
-            n_points = fleets.shape[0]
-
-            lam0 = jnp.asarray(np.stack([m.mean(axis=0) / dt for m in mats]),
-                               jnp.float32)                 # (C, F)
-            gaps = jnp.asarray(np.stack(gq_l), jnp.float32)
-            alive_tab = jnp.asarray(np.stack(at_l), jnp.float32)
-            tail_tab = jnp.asarray(np.stack(tt_l), jnp.float32)
-
-            ft_s = topo.fail_time(duration_s)
+            # profile-wide constants: every cell shares the function ids
+            st = _run_statics(traces[0], policy, sim, dt, billing=billing,
+                              provision_s=provision_s, nbins=nbins,
+                              n_ticks=n_ticks, warmup_frac=warmup_frac)
+            # (C, F) rates and gap quantiles, (C, F, K) gap tables
+            lam0, gaps, alive_tab, tail_tab = (
+                jnp.asarray(np.stack(x), jnp.float32)
+                for x in ([m.mean(axis=0) / dt for m in mats], gq_l, at_l,
+                          tt_l))
+            ft_s = topo.fail_time(traces[0].duration_s)
             fail_tick = -1 if ft_s is None else int(round(ft_s / dt))
-            floor_np = topo.floor_schedule(n_ticks, dt, duration_s)  # (T, C)
+            # (T, C) scheduled node floors
+            floor_np = topo.floor_schedule(n_ticks, dt, traces[0].duration_s)
             k = len(topo.reactive)
-            trig = None
-            if k:
-                trig = (jnp.asarray([t.util_high for t in topo.reactive],
-                                    jnp.float32),
-                        jnp.asarray([t.change for t in topo.reactive],
-                                    jnp.float32),
-                        jnp.asarray([max(1, round(t.hold_s / dt))
-                                     for t in topo.reactive], jnp.float32),
-                        jnp.asarray([max(1, round(t.cooldown_s / dt))
-                                     for t in topo.reactive], jnp.float32))
+            # (util_high, change, hold_ticks, cool_ticks), each (K,)
+            trig = tuple(jnp.asarray(v, jnp.float32) for v in zip(*(
+                (t.util_high, t.change, max(1, round(t.hold_s / dt)),
+                 max(1, round(t.cooldown_s / dt))) for t in topo.reactive))
+            ) if k else None
             static_nodes = jnp.asarray(topo.cell_nodes(num_nodes),
                                        jnp.float32)
-
+            edges_j = jnp.asarray(st.edges)
             fleets_j = jnp.asarray(fleets, jnp.float32)
             pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
                                   pols)
 
             def init_point(fl):
-                def init_cell(sn):
-                    return _init_state(f, cold_ticks, wbuf, prov_ticks,
-                                       fl[0] if has_fleet else sn)
-                return (jax.vmap(init_cell)(static_nodes),
-                        jnp.zeros((c_n, k)), jnp.zeros((c_n, k)),
-                        jnp.zeros((c_n, k)))
+                cells = jax.vmap(lambda sn: _init_state(
+                    f, st.cold_ticks, st.wbuf, st.prov_ticks,
+                    fl[0] if has_fleet else sn))(static_nodes)
+                return (cells,) + tuple(jnp.zeros((c_n, k)) for _ in range(3))
 
             state = jax.vmap(init_point)(fleets_j)
 
-        hist = np.zeros((n_points, f, nbins))
-        arrtot = np.zeros((n_points, f))
-        sums = np.zeros((n_points, len(_ACC_NAMES)))
-        n = np.zeros(n_points)
-        csums = np.zeros((n_points, c_n, len(_ACC_NAMES)))
-        cn = np.zeros((n_points, c_n))
-        for index, t0 in enumerate(range(0, n_ticks, chunk_ticks)):
-            with runs.span("chunk", index=index):
-                a, fl_c = _upload_chunks(arr_np[t0:t0 + chunk_ticks],
-                                         floor_np[t0:t0 + chunk_ticks],
-                                         ticks=chunk_ticks)
-                with runs.span("chunk.dispatch"):
-                    state, out = _cells_chunk_batch(
-                        state, a, fl_c, lam0, gaps, alive_tab, tail_tab, dur,
-                        mem, billed_w, pols_j, fleets_j, trig, cpu_consts,
-                        static_nodes, edges_j, jnp.asarray(t0, jnp.int32),
-                        warm_tick=warm_tick, total_ticks=n_ticks,
-                        family=policy.family, dt=dt, cold_ticks=cold_ticks,
-                        wbuf=wbuf, prov_ticks=prov_ticks,
-                        has_fleet=has_fleet, fail_cell=int(topo.fail_cell),
-                        fail_tick=fail_tick,
-                        route_skew_static=float(topo.route_skew),
-                        spill_static=float(topo.spill_threshold))
-                out = _read_back(out)
-                with runs.span("chunk.accumulate"):
-                    hist += out[0]
-                    arrtot += out[1]
-                    sums += out[2]
-                    n += out[3]
-                    csums += out[4]
-                    cn += out[5]
-        iid = get_family(policy.family).synchronous_tail
-        rows = []
-        for i in range(n_points):
-            with runs.span("summary", point=i):
-                rows.append(_acc_summary(hist[i], arrtot[i], sums[i], n[i],
-                                         edges, dur_median, dur_sigma,
-                                         sim.warm_latency_s, dt,
-                                         iid_tail=iid))
+        def dispatch(state, chunks, t0):
+            return _cells_chunk_batch(
+                state, *chunks, lam0, gaps, alive_tab, tail_tab, st.dur,
+                st.mem, st.billed_w, pols_j, fleets_j, trig, st.cpu_consts,
+                static_nodes, edges_j, t0, warm_tick=st.warm_tick,
+                total_ticks=n_ticks, family=policy.family, dt=dt,
+                cold_ticks=st.cold_ticks, wbuf=st.wbuf,
+                prov_ticks=st.prov_ticks, has_fleet=has_fleet,
+                fail_cell=int(topo.fail_cell), fail_tick=fail_tick,
+                route_skew_static=float(topo.route_skew),
+                spill_static=float(topo.spill_threshold))
+
+        acc = _drive_chunks(state, (arr_np, floor_np), dispatch,
+                            n_ticks=n_ticks, chunk_ticks=chunk_ticks)
+        rows = _point_rows(acc, st, policy.family, sim, dt, f=f)
     if detail is not None:
-        detail["cell_rows"] = _cell_rows(csums[0], cn[0], dt)
+        detail["cell_rows"] = _cell_rows(acc[4][0], acc[5][0], dt)
     return rows
 
 

@@ -59,7 +59,7 @@ from __future__ import annotations
 import dataclasses
 import sys
 from functools import partial
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,7 +69,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.eventsim import SimConfig
 from repro.core.policy_api import (HYBRID_MIN_KA_S, PolicyObs,  # noqa: F401
                                    get_family)
-from repro.core.trace import Trace, gap_statistics, rate_matrix
+from repro.core.trace import gap_statistics, rate_matrix
+from repro.distributed.sharding import device_mesh
 from repro.obs import runs
 from repro.obs.telemetry import TELEM_ATTR, TELEM_SERIES, assemble_telemetry
 
@@ -168,8 +169,9 @@ def _make_step(arrivals, dur, mem, billed_w, lam0, gaps, gap_tab, pol, fleet,
                static_nodes, *, family: str, dt: float, cold_ticks: int,
                wbuf: int, prov_ticks: int, has_fleet: bool,
                telem: bool = False, weights=None):
-    """One simulated tick, shared by the full-history scan (`_sim_impl`) and
-    the chunked-summary scan (`_chunk_impl`) so the policy math exists once.
+    """One simulated tick, shared by the chunked-summary scans
+    (`_chunk_impl`, `cells.fluid._cells_chunk_impl`) and the learned
+    policy's loss, so the policy math exists once.
 
     ``lam0`` is the (F,) long-run mean arrival rate per function, the
     input to the renewal-matched keepalive expiry (see
@@ -510,165 +512,72 @@ def _make_step(arrivals, dur, mem, billed_w, lam0, gaps, gap_tab, pol, fleet,
     return step
 
 
-def _sim_impl(arrivals, dur, mem, billed_w, lam0, gaps, gap_tab, pol, fleet,
-              cpu_consts,
-              static_nodes, *, family: str, n_ticks: int, dt: float,
-              cold_ticks: int, wbuf: int, prov_ticks: int, has_fleet: bool):
-    step = _make_step(arrivals, dur, mem, billed_w, lam0, gaps, gap_tab,
-                      pol, fleet, cpu_consts,
-                      static_nodes, family=family, dt=dt,
-                      cold_ticks=cold_ticks, wbuf=wbuf, prov_ticks=prov_ticks,
-                      has_fleet=has_fleet)
-    init_nodes = fleet[0] if has_fleet else jnp.asarray(static_nodes, jnp.float32)
-    init = _init_state(dur.shape[0], cold_ticks, wbuf, prov_ticks, init_nodes)
-    _, ys = jax.lax.scan(step, init, jnp.arange(n_ticks))
-    return ys
+class _Statics(NamedTuple):
+    """Per-run constants of a fluid run: everything the step programs and
+    the summary need besides the arrivals and the gap statistics."""
+    dur: jax.Array            # (F,) mean service time, floored at dt / 4
+    mem: jax.Array            # (F,) instance memory incl. overhead (MB)
+    cold_ticks: int           # cold-start pipeline depth
+    wbuf: int                 # policy window buffer (1 unless windowed)
+    cpu_consts: tuple         # SimConfig CPU prices, in _make_step's order
+    billed_w: jax.Array       # (F,) expected billed GB-s per completion
+    dur_median: np.ndarray    # (F,) per-request duration lognormal
+    dur_sigma: np.ndarray
+    prov_ticks: int           # node provisioning pipeline depth
+    edges: np.ndarray         # delay-histogram bin edges
+    warm_tick: int            # first measured tick
 
 
-_simulate = partial(jax.jit, static_argnames=(
-    "family", "n_ticks", "dt", "cold_ticks", "wbuf", "prov_ticks",
-    "has_fleet"))(_sim_impl)
-
-
-@dataclasses.dataclass
-class JaxSimResult:
-    delay: np.ndarray      # (T, F) per-tick queue delay estimate
-    arrivals: np.ndarray   # (T, F)
-    arr_delayed: np.ndarray  # (T, F) arrivals NOT served warm this tick
-    instances: np.ndarray  # (T,)
-    mem_total: np.ndarray  # (T,)
-    mem_busy: np.ndarray   # (T,)
-    creations: np.ndarray  # (T,)
-    cpu_worker: np.ndarray
-    cpu_master: np.ndarray
-    useful: np.ndarray
-    nodes: np.ndarray      # (T,) billable node count (static fleet: constant)
-    completions: np.ndarray  # (T,) fluid request completions
-    spot_nodes: np.ndarray  # (T,) billable SPOT share of nodes (0 w/o spot)
-    billed_gb_s: np.ndarray  # (T,) billed GB-s (repro.fleet.billing weights)
-    dt: float
-    dur: np.ndarray        # (F,)
-    fleet: Optional[JaxFleet] = None
-    # per-request duration distribution (for the slowdown mixture); falls
-    # back to a near-degenerate lognormal at the mean when absent
-    dur_median: Optional[np.ndarray] = None   # (F,)
-    dur_sigma: Optional[np.ndarray] = None    # (F,)
-    warm_latency_s: float = 0.008
-    # sync policies produce iid per-request cold-start tails (finite-sample
-    # percentile correction applies); async tails are backlog episodes
-    sync_tail: bool = True
-
-
-_YS_NAMES = ["delay", "arrivals", "arr_delayed", "instances", "mem_total",
-             "mem_busy", "creations", "cpu_worker", "cpu_master", "useful",
-             "nodes", "completions", "spot_nodes", "billed_gb_s"]
-
-
-def _prep_static(trace: Trace, policy: JaxPolicy, sim: SimConfig, dt: float):
-    """Everything ``_sim_impl`` needs except the (T, F) arrivals matrix."""
-    dur_mean = trace.profile.dur_median * np.exp(trace.profile.dur_sigma ** 2 / 2)
-    dur = jnp.asarray(np.maximum(dur_mean, dt * 0.25), jnp.float32)
-    mem = jnp.asarray(trace.profile.memory_mb + sim.instance_overhead_mb, jnp.float32)
-    cold_ticks = max(1, int(round(sim.cold_start_s / dt)))
+def _run_statics(trace, policy: JaxPolicy, sim: SimConfig, dt: float, *,
+                 billing, provision_s: float, nbins: int, n_ticks: int,
+                 warmup_frac: float) -> _Statics:
+    """The per-run constants every fluid engine entry builds from the
+    trace's function profile, the policy, the simulator config and the
+    horizon.
+    ``billing`` is a ``repro.fleet.billing`` profile or name (default
+    ``ideal``: no rounding, so a billed weight is E[duration] x configured
+    GB); imported lazily, so ``repro.core`` needs no ``repro.fleet``."""
+    from repro.fleet.billing import get_profile
+    prof = trace.profile
+    dur_mean = prof.dur_median * np.exp(prof.dur_sigma ** 2 / 2)
     wbuf = max(1, int(round(policy.window_s / dt))) \
         if get_family(policy.family).uses_window else 1
-    cpu_consts = (sim.cpu_create_worker_s, sim.cpu_create_master_s,
-                  sim.cpu_teardown_worker_s, sim.cpu_teardown_master_s,
-                  sim.cpu_request_s, sim.cpu_idle_per_s,
-                  sim.cpu_worker_floor_per_node_s,
-                  sim.cpu_master_floor_per_s)
-    return dur, mem, cold_ticks, wbuf, cpu_consts
-
-
-def _prep(trace: Trace, policy: JaxPolicy, sim: SimConfig, dt: float):
-    arr = jnp.asarray(rate_matrix(trace, dt))
-    dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(trace, policy, sim, dt)
-    return arr, dur, mem, cold_ticks, wbuf, cpu_consts
-
-
-def _billed_weights(trace: Trace, billing) -> jnp.ndarray:
-    """(F,) expected billed GB-s per completion under a billing profile
-    (default: the ``ideal`` profile — no rounding, so the weight is just
-    E[duration] x configured GB).  Imported lazily: ``repro.core`` stays
-    free of a hard ``repro.fleet`` dependency."""
-    from repro.fleet.billing import get_profile
-    prof = get_profile(billing if billing is not None else "ideal")
-    return jnp.asarray(prof.billed_weights(trace.profile), jnp.float32)
-
-
-def simulate(trace: Trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),
-             dt: float = 1.0, num_nodes: int = 8,
-             fleet: Optional[JaxFleet] = None, billing=None) -> JaxSimResult:
-    arr, dur, mem, cold_ticks, wbuf, cpu_consts = _prep(trace, policy, sim, dt)
-    billed_w = _billed_weights(trace, billing)
-    has_fleet = fleet is not None
-    prov_ticks = max(1, int(round((fleet.provision_s if has_fleet else 0.0) / dt)))
-    pol = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32), policy.params())
-    fl = jnp.asarray(fleet.params() if has_fleet else np.zeros(len(_PFLEET)),
-                     jnp.float32)
-    lam0 = jnp.asarray(np.asarray(arr).mean(axis=0) / dt, jnp.float32)
-    gq, alive_tab, tail_tab = gap_statistics(trace)
-    gaps = jnp.asarray(gq, jnp.float32)
-    gap_tab = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
-                           (alive_tab, tail_tab))
-    ys = _simulate(arr, dur, mem, billed_w, lam0, gaps, gap_tab, pol, fl,
-                   cpu_consts, float(num_nodes),
-                   family=policy.family, n_ticks=arr.shape[0], dt=dt,
-                   cold_ticks=cold_ticks, wbuf=wbuf, prov_ticks=prov_ticks,
-                   has_fleet=has_fleet)
-    vals = {n: np.asarray(v) for n, v in zip(_YS_NAMES, ys)}
-    return JaxSimResult(dt=dt, dur=np.asarray(dur), fleet=fleet,
-                        dur_median=np.asarray(trace.profile.dur_median),
-                        dur_sigma=np.asarray(trace.profile.dur_sigma),
-                        warm_latency_s=sim.warm_latency_s,
-                        sync_tail=get_family(policy.family).synchronous_tail,
-                        **vals)
-
-
-def summarize(res: JaxSimResult, warmup_frac: float = 0.5,
-              nbins: int = 256) -> dict:
-    t0 = int(len(res.instances) * warmup_frac)
-    sl = slice(t0, None)
-    # arrival-weighted per-function delay histogram -> p99 of the
-    # per-request slowdown mixture (same estimator as the chunked path):
-    # warm-served arrivals land in the zero-delay bin, delayed arrivals
-    # carry the tick's delay estimate
-    delays, weights = res.delay[sl], res.arrivals[sl]
-    delayed = res.arr_delayed[sl]
-    f = delays.shape[1]
-    edges = _delay_edges(nbins)
-    b = np.clip(np.searchsorted(edges, delays, side="right"), 0, nbins - 1)
-    hist = np.zeros((f, nbins))
-    fn_idx = np.broadcast_to(np.arange(f), delays.shape)
-    np.add.at(hist, (fn_idx, b), delayed)
-    hist[:, 0] += (weights - delayed).sum(axis=0)
-    med = res.dur_median if res.dur_median is not None else np.asarray(res.dur)
-    sig = res.dur_sigma if res.dur_sigma is not None else np.zeros(f)
-    # delegate to the chunked path's row builder so every metric formula
-    # exists exactly once (the "memory-bounded twin" contract)
-    sums = np.asarray([res.instances[sl].sum(), res.mem_total[sl].sum(),
-                       res.mem_busy[sl].sum(), res.creations[sl].sum(),
-                       res.cpu_worker[sl].sum(), res.cpu_master[sl].sum(),
-                       res.useful[sl].sum(), res.nodes[sl].sum(),
-                       res.completions[sl].sum(), res.spot_nodes[sl].sum(),
-                       res.billed_gb_s[sl].sum()])
-    return _acc_summary(hist, weights.sum(axis=0), sums,
-                        len(res.instances) - t0, edges, med, sig,
-                        res.warm_latency_s, res.dt, iid_tail=res.sync_tail)
+    return _Statics(
+        dur=jnp.asarray(np.maximum(dur_mean, dt * 0.25), jnp.float32),
+        mem=jnp.asarray(prof.memory_mb + sim.instance_overhead_mb,
+                        jnp.float32),
+        cold_ticks=max(1, int(round(sim.cold_start_s / dt))),
+        wbuf=wbuf,
+        cpu_consts=(sim.cpu_create_worker_s, sim.cpu_create_master_s,
+                    sim.cpu_teardown_worker_s, sim.cpu_teardown_master_s,
+                    sim.cpu_request_s, sim.cpu_idle_per_s,
+                    sim.cpu_worker_floor_per_node_s,
+                    sim.cpu_master_floor_per_s),
+        billed_w=jnp.asarray(get_profile(
+            billing if billing is not None else "ideal").billed_weights(prof),
+            jnp.float32),
+        dur_median=np.asarray(prof.dur_median),
+        dur_sigma=np.asarray(prof.dur_sigma),
+        prov_ticks=max(1, int(round(provision_s / dt))),
+        edges=_delay_edges(nbins),
+        warm_tick=int(n_ticks * warmup_frac))
 
 
 # ---------------------------------------------------------------------------
 # chunked scan: production scale without per-tick histories
 # ---------------------------------------------------------------------------
 #
-# ``simulate`` materializes two (T, F) arrays plus nine (T,) series — fine for
-# a 400-function / 80-minute trace, ruinous for the 2000-function Fig. 9
-# replay and for vmapped sweeps (P x T x F).  The chunked path runs the SAME
-# ``_make_step`` tick function, but the scan emits nothing per tick: summary
-# statistics (per-function arrival-weighted delay histograms + scalar sums)
-# live in the scan carry, the time axis is segmented into fixed-size chunks,
-# and the carry buffers are donated between chunk calls, so peak device
-# memory is O(F * BINS + chunk * F) regardless of trace length.
+# The scan emits nothing per tick: summary statistics (per-function
+# arrival-weighted delay histograms + scalar sums) live in the scan carry,
+# the time axis is segmented into fixed-size chunks, and the carry buffers
+# are donated between chunk calls, so peak device memory is
+# O(F * BINS + chunk * F) regardless of trace length — the 2000-function
+# Fig. 9 replay, vmapped sweeps (P x F) and 100k-function planet replays
+# alike.  Three step programs run the chunks (``_chunk_batch``, its
+# function-sharded twin ``_chunk_batch_fnshard`` and the multi-cell
+# ``repro.cells.fluid._cells_chunk_batch``); one host path around them
+# builds the statics (``_run_statics``), runs the chunk loop
+# (``_drive_chunks``) and solves each point's row (``_point_rows``).
 
 # scalar per-tick series accumulated post-warmup (order matches ys[3:];
 # ys[0:3] are the per-function delay / arrivals / delayed-arrivals vectors)
@@ -682,6 +591,13 @@ def _delay_edges(nbins: int) -> np.ndarray:
     ~1.075x per bin at nbins=256, so histogram p99s land within a few
     percent of the exact per-tick percentile."""
     return np.logspace(-3, 5, nbins - 1, dtype=np.float32)
+
+
+def _delay_bin(edges, delay, nbins: int):
+    """Histogram bin of each queueing delay (traced, inside a step):
+    bin 0 below the first edge, the last bin above the top one."""
+    return jnp.clip(jnp.searchsorted(edges, delay, side="right"), 0,
+                    nbins - 1)
 
 
 def _bin_reps(edges: np.ndarray) -> np.ndarray:
@@ -916,8 +832,7 @@ def _chunk_impl(state, arr_chunk, lam0, gaps, gap_tab, dur, mem, billed_w,
         g = tick0 + i
         m = ((g >= warm_tick) & (g < total_ticks)).astype(jnp.float32)
         with jax.named_scope("delay_histogram"):
-            b = jnp.clip(jnp.searchsorted(edges, delay, side="right"), 0,
-                         nbins - 1)
+            b = _delay_bin(edges, delay, nbins)
             hist = hist.at[jnp.arange(f), b].add(arr_delayed * m)
             hist = hist.at[:, 0].add((arr - arr_delayed) * m)
         out = (st, hist, arrtot + arr * m,
@@ -943,7 +858,7 @@ def _chunk_impl(state, arr_chunk, lam0, gaps, gap_tab, dur, mem, billed_w,
 
 def _acc_summary(hist, arrtot, sums, n, edges, dur_median, dur_sigma, warm,
                  dt, iid_tail: bool = True, fn_weights=None) -> dict:
-    """Build the ``summarize``-compatible metric row from chunk partials."""
+    """Build one point's metric row from its accumulated chunk partials."""
     geo = _slowdown_geomean(hist, arrtot, edges, dur_median, dur_sigma, warm,
                             iid_tail=iid_tail, fn_weights=fn_weights)
     s = dict(zip(_ACC_NAMES, sums))
@@ -1111,74 +1026,41 @@ def _chunked_summaries(trace, policy: JaxPolicy, pols: dict,
                        chunk_ticks: int, warmup_frac: float,
                        nbins: int, telemetry: int = 0,
                        billing=None, devices: int = 0) -> list[dict]:
-    """Run a batch of policy/fleet parameter points through the chunked scan
-    (vmapped over points, host loop over time chunks, carry donated) and
-    return one ``summarize``-style dict per point.  ``pols`` is a stacked
-    params pytree (see ``stack_params``); ``policy`` supplies the family
-    and the structural knobs.
+    """Run a batch of policy/fleet parameter points (``pols`` a stacked
+    params pytree, see ``stack_params``; ``policy`` gives the family and
+    the structural knobs) through the chunked scan: one metric row each.
 
     ``devices > 0`` shards the dispatch over a 1-D mesh of that many local
-    devices (repro.distributed.sharding.device_mesh).  No-fleet runs shard
-    the FUNCTION axis via ``shard_map`` (F is padded to a mesh multiple
-    with inert zero-rate functions, trimmed from the results); fleet runs
-    couple functions through per-tick capacity reductions, so they shard
-    the POINT axis instead — the largest divisor of the point count that
-    fits the device budget, falling back to the unsharded dispatch when the
-    batch cannot split; a stderr note says when fewer devices than
-    requested carry the batch.  ``devices=0`` is the legacy single-device
-    path."""
+    devices.  No-fleet runs shard the FUNCTION axis via ``shard_map`` (F
+    padded to a mesh multiple with inert zero-rate functions, trimmed from
+    the rows); fleet runs couple functions through per-tick capacity
+    reductions, so they shard the POINT axis over the largest divisor of
+    the point count within the budget (a stderr note says when that is
+    fewer devices), unsharded when the batch cannot split."""
     n_points = fleets.shape[0]
-    devices = int(devices)
+    devices, telemetry = int(devices), int(telemetry)
     with runs.run(points=n_points, devices=devices) as info:
         with runs.span("prep.rate_matrix"):
             arr_np = np.asarray(rate_matrix(trace, dt))
-        n_ticks, f = arr_np.shape
+        n_ticks, f_orig = arr_np.shape
         chunk_ticks = max(1, min(chunk_ticks, n_ticks))
-        info.update(functions=f, ticks=n_ticks, chunk_ticks=chunk_ticks)
+        info.update(functions=f_orig, ticks=n_ticks, chunk_ticks=chunk_ticks)
         gq, alive_tab, tail_tab = gap_statistics(trace)
 
         with runs.span("prep.device"):
-            dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(
-                trace, policy, sim, dt)
-            billed_w = _billed_weights(trace, billing)
-            dur_median = np.asarray(trace.profile.dur_median)
-            dur_sigma = np.asarray(trace.profile.dur_sigma)
+            st = _run_statics(trace, policy, sim, dt, billing=billing,
+                              provision_s=provision_s, nbins=nbins,
+                              n_ticks=n_ticks, warmup_frac=warmup_frac)
             weights_np = getattr(trace, "weights", None)
-            prov_ticks = max(1, int(round(provision_s / dt)))
-            edges = _delay_edges(nbins)
-            warm_tick = int(n_ticks * warmup_frac)
             lam_np = arr_np.mean(axis=0) / dt
-
             fn_mesh = pt_sharding = None
-            f_orig = f
             if devices > 0 and not has_fleet:
-                from repro.distributed.sharding import device_mesh
                 fn_mesh = device_mesh(devices, "functions")
-                pad = (-f) % devices
-                if pad:
-                    # inert padding functions: zero arrivals -> zero
-                    # instances, creations, memory and histogram mass
-                    # (trimmed below anyway)
-                    arr_np = np.concatenate(
-                        [arr_np, np.zeros((n_ticks, pad), arr_np.dtype)],
-                        axis=1)
-                    dur = jnp.concatenate([dur, jnp.ones(pad, dur.dtype)])
-                    mem = jnp.concatenate([mem, jnp.zeros(pad, mem.dtype)])
-                    billed_w = jnp.concatenate(
-                        [billed_w, jnp.zeros(pad, billed_w.dtype)])
-                    lam_np = np.concatenate([lam_np, np.zeros(pad)])
-                    gq = np.concatenate([gq, np.full(pad, trace.duration_s)])
-                    # never-observed-gap convention: alive = ka, tail = 1
-                    from repro.core.trace import KA_GRID
-                    alive_tab = np.concatenate(
-                        [alive_tab,
-                         np.broadcast_to(KA_GRID, (pad, len(KA_GRID)))])
-                    tail_tab = np.concatenate(
-                        [tail_tab, np.ones((pad, len(KA_GRID)))])
-                    if weights_np is not None:
-                        weights_np = np.concatenate(
-                            [weights_np, np.zeros(pad)])
-                    f += pad
+                if f_orig % devices:
+                    st, arr_np, lam_np, gq, alive_tab, tail_tab, weights_np \
+                        = _pad_functions((-f_orig) % devices, st, arr_np,
+                                         lam_np, gq, alive_tab, tail_tab,
+                                         weights_np, trace.duration_s)
             elif devices > 0 and has_fleet:
                 d = _largest_divisor(n_points, devices)
                 if devices > 1 and d < devices:
@@ -1186,96 +1068,131 @@ def _chunked_summaries(trace, policy: JaxPolicy, pols: dict,
                           f"the {devices} requested device(s)",
                           file=sys.stderr)
                 if d > 1:
-                    from repro.distributed.sharding import device_mesh
                     pt_sharding = NamedSharding(device_mesh(d, "points"),
                                                 P("points"))
+            f = arr_np.shape[1]
 
             lam_eff = jnp.broadcast_to(jnp.asarray(lam_np, jnp.float32),
                                        (n_points, f))
             gaps = jnp.asarray(gq, jnp.float32)
             gap_tab = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
                                    (alive_tab, tail_tab))
-            edges_j = jnp.asarray(edges)
+            edges_j = jnp.asarray(st.edges)
             weights_j = None if weights_np is None \
                 else jnp.asarray(weights_np, jnp.float32)
 
             def init_point(fl):
                 init_nodes = fl[0] if has_fleet \
                     else jnp.asarray(float(num_nodes))
-                return _init_state(f, cold_ticks, wbuf, prov_ticks,
+                return _init_state(f, st.cold_ticks, st.wbuf, st.prov_ticks,
                                    init_nodes)
 
-            state = jax.vmap(init_point)(jnp.asarray(fleets, jnp.float32))
+            fleets_j = jnp.asarray(fleets, jnp.float32)
+            state = jax.vmap(init_point)(fleets_j)
             pols_j = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
                                   pols)
-            fleets_j = jnp.asarray(fleets, jnp.float32)
             if pt_sharding is not None:
                 # point-axis sharding: place the vmapped batch over the mesh
                 # and let GSPMD partition the existing compiled dispatch —
                 # frontier candidates run as grid-points x devices in one
                 # call
-                state = jax.device_put(state, pt_sharding)
-                lam_eff = jax.device_put(lam_eff, pt_sharding)
-                pols_j = jax.device_put(pols_j, pt_sharding)
-                fleets_j = jax.device_put(fleets_j, pt_sharding)
+                state, lam_eff, pols_j, fleets_j = jax.device_put(
+                    (state, lam_eff, pols_j, fleets_j), pt_sharding)
 
-        hist = np.zeros((n_points, f, nbins))
-        arrtot = np.zeros((n_points, f))
-        sums = np.zeros((n_points, len(_ACC_NAMES)))
-        n = np.zeros(n_points)
-        telemetry = int(telemetry)
-        tser = np.zeros((n_points, max(telemetry, 1), len(TELEM_SERIES)))
-        tcnt = np.zeros((n_points, max(telemetry, 1)))
-        tattr = np.zeros((n_points, len(TELEM_ATTR)))
-        for index, t0 in enumerate(range(0, n_ticks, chunk_ticks)):
-            with runs.span("chunk", index=index):
-                a = _upload_chunks(arr_np[t0:t0 + chunk_ticks],
-                                   ticks=chunk_ticks)[0]
-                with runs.span("chunk.dispatch"):
-                    if fn_mesh is not None:
-                        state, out = _chunk_batch_fnshard(
-                            state, a, lam_eff, gaps, gap_tab, dur, mem,
-                            billed_w, pols_j, fleets_j, edges_j,
-                            jnp.asarray(t0, jnp.int32), weights_j,
-                            mesh=fn_mesh, cpu_consts=cpu_consts,
-                            static_nodes=float(num_nodes),
-                            warm_tick=warm_tick, total_ticks=n_ticks,
-                            family=policy.family, dt=dt,
-                            cold_ticks=cold_ticks, wbuf=wbuf,
-                            prov_ticks=prov_ticks, telem_slots=telemetry)
-                    else:
-                        state, out = _chunk_batch(
-                            state, a, lam_eff, gaps, gap_tab, dur, mem,
-                            billed_w, pols_j, fleets_j,
-                            cpu_consts, float(num_nodes), edges_j,
-                            jnp.asarray(t0, jnp.int32), warm_tick=warm_tick,
-                            total_ticks=n_ticks, family=policy.family,
-                            dt=dt, cold_ticks=cold_ticks, wbuf=wbuf,
-                            prov_ticks=prov_ticks, has_fleet=has_fleet,
-                            telem_slots=telemetry, weights=weights_j)
-                out = _read_back(out)
-                with runs.span("chunk.accumulate"):
-                    hist += out[0]
-                    arrtot += out[1]
-                    sums += out[2]
-                    n += out[3]
-                    if telemetry:
-                        tser += out[4]
-                        tcnt += out[5]
-                        tattr += out[6]
-        iid = get_family(policy.family).synchronous_tail
-        fw = None if weights_np is None else np.asarray(weights_np)[:f_orig]
-        rows = []
-        for i in range(n_points):
-            with runs.span("summary", point=i):
-                rows.append(_acc_summary(
-                    hist[i, :f_orig], arrtot[i, :f_orig], sums[i], n[i],
-                    edges, dur_median, dur_sigma, sim.warm_latency_s, dt,
-                    iid_tail=iid, fn_weights=fw))
+        statics = dict(warm_tick=st.warm_tick, total_ticks=n_ticks,
+                       family=policy.family, dt=dt, cold_ticks=st.cold_ticks,
+                       wbuf=st.wbuf, prov_ticks=st.prov_ticks,
+                       telem_slots=telemetry)
+
+        def dispatch(state, chunks, t0):
+            if fn_mesh is not None:
+                return _chunk_batch_fnshard(
+                    state, chunks[0], lam_eff, gaps, gap_tab, st.dur, st.mem,
+                    st.billed_w, pols_j, fleets_j, edges_j, t0, weights_j,
+                    mesh=fn_mesh, cpu_consts=st.cpu_consts,
+                    static_nodes=float(num_nodes), **statics)
+            return _chunk_batch(
+                state, chunks[0], lam_eff, gaps, gap_tab, st.dur, st.mem,
+                st.billed_w, pols_j, fleets_j, st.cpu_consts,
+                float(num_nodes), edges_j, t0, has_fleet=has_fleet,
+                weights=weights_j, **statics)
+
+        acc = _drive_chunks(state, (arr_np,), dispatch, n_ticks=n_ticks,
+                            chunk_ticks=chunk_ticks)
+        rows = _point_rows(acc, st, policy.family, sim, dt, f=f_orig,
+                           fn_weights=getattr(trace, "weights", None))
     if telemetry:
         for i, row in enumerate(rows):
-            row["telemetry"] = assemble_telemetry(tser[i], tcnt[i], tattr[i],
-                                                  n_ticks, dt)
+            row["telemetry"] = assemble_telemetry(
+                *(x[i] for x in acc[4:]), n_ticks, dt)
+    return rows
+
+
+def _pad_functions(pad, st, arr_np, lam_np, gq, alive_tab, tail_tab,
+                   weights_np, duration_s):
+    """Append ``pad`` inert functions to every per-function input of a
+    function-sharded run, so F splits evenly over the mesh: zero arrivals
+    -> zero instances, creations, memory and histogram mass (trimmed from
+    the rows anyway)."""
+    from repro.core.trace import KA_GRID
+    n_ticks = arr_np.shape[0]
+    arr_np = np.concatenate(
+        [arr_np, np.zeros((n_ticks, pad), arr_np.dtype)], axis=1)
+    st = st._replace(
+        dur=jnp.concatenate([st.dur, jnp.ones(pad, st.dur.dtype)]),
+        mem=jnp.concatenate([st.mem, jnp.zeros(pad, st.mem.dtype)]),
+        billed_w=jnp.concatenate(
+            [st.billed_w, jnp.zeros(pad, st.billed_w.dtype)]))
+    lam_np = np.concatenate([lam_np, np.zeros(pad)])
+    gq = np.concatenate([gq, np.full(pad, duration_s)])
+    # never-observed-gap convention: alive = ka, tail = 1
+    alive_tab = np.concatenate(
+        [alive_tab, np.broadcast_to(KA_GRID, (pad, len(KA_GRID)))])
+    tail_tab = np.concatenate([tail_tab, np.ones((pad, len(KA_GRID)))])
+    if weights_np is not None:
+        weights_np = np.concatenate([weights_np, np.zeros(pad)])
+    return st, arr_np, lam_np, gq, alive_tab, tail_tab, weights_np
+
+
+def _drive_chunks(state, per_tick, dispatch, *, n_ticks: int,
+                  chunk_ticks: int) -> list:
+    """The chunk loop of every fluid engine entry.  Each time chunk of the
+    host (T, ...) arrays in ``per_tick`` goes to the device,
+    ``dispatch(state, chunks, t0)`` advances the (donated) state through
+    one step program and returns it with the chunk's statistics, which
+    come back to the host and add into float64 sums, one per leaf, in
+    chunk order."""
+    sums = None
+    for index, t0 in enumerate(range(0, n_ticks, chunk_ticks)):
+        with runs.span("chunk", index=index):
+            chunks = _upload_chunks(*(x[t0:t0 + chunk_ticks]
+                                      for x in per_tick), ticks=chunk_ticks)
+            with runs.span("chunk.dispatch"):
+                state, out = dispatch(state, chunks,
+                                      jnp.asarray(t0, jnp.int32))
+            out = _read_back(out)
+            with runs.span("chunk.accumulate"):
+                if sums is None:
+                    sums = [np.zeros(x.shape) for x in out]
+                for total, x in zip(sums, out):
+                    total += x
+    return sums
+
+
+def _point_rows(acc, st: _Statics, family: str, sim: SimConfig, dt: float,
+                *, f: int, fn_weights=None) -> list[dict]:
+    """One metric row per batch point from the accumulated chunk sums
+    (histogram, arrival totals, metric sums, measured ticks first), the
+    function axis trimmed to the first ``f`` functions."""
+    hist, arrtot, sums, n = acc[:4]
+    iid = get_family(family).synchronous_tail
+    rows = []
+    for i in range(len(n)):
+        with runs.span("summary", point=i):
+            rows.append(_acc_summary(
+                hist[i, :f], arrtot[i, :f], sums[i], n[i], st.edges,
+                st.dur_median, st.dur_sigma, sim.warm_latency_s, dt,
+                iid_tail=iid, fn_weights=fn_weights))
     return rows
 
 
@@ -1311,31 +1228,23 @@ def simulate_chunked(trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),
                      fleet: Optional[JaxFleet] = None, chunk_ticks: int = 512,
                      warmup_frac: float = 0.5, nbins: int = 256,
                      *, spec=None) -> dict:
-    """Memory-bounded twin of ``summarize(simulate(...))``: same step math,
-    same metric keys, but summary statistics are accumulated inside a
-    segmented scan so arbitrarily long / wide traces (the 2000-function
-    Fig. 9 replay, fig9_planet's 100k functions, and beyond) never
-    materialize (T, F) histories.  ``trace`` may be an event-level
-    ``Trace`` or a pre-binned ``RateTrace`` (optionally clustered into
-    weighted super-functions).
+    """Replay one trace under one policy (and optional node fleet) through
+    the chunked scan and return its metric row.  Summary statistics are
+    accumulated inside a segmented scan, so arbitrarily long / wide traces
+    (the 2000-function Fig. 9 replay, fig9_planet's 100k functions, and
+    beyond) never materialize (T, F) histories.  ``trace`` may be an
+    event-level ``Trace`` or a pre-binned ``RateTrace`` (optionally
+    clustered into weighted super-functions).
 
     ``spec`` (a ``repro.core.runspec.RunSpec``) carries the run knobs this
-    engine consumes: ``telemetry`` slots, the ``billing`` profile, and
-    ``devices`` for the sharded dispatch (function axis here; see
-    ``_chunked_summaries``).  It is the only way to pass them — the loose
-    ``telemetry=`` / ``billing=`` shim kwargs were removed.
-
-    ``telemetry=S`` (static, default off) rides S downsampled per-tick
-    series slots plus attribution sums in the scan carry — constant memory —
-    and attaches the assembled ``telemetry`` dict (repro.obs.telemetry) to
-    the returned row.  ``telemetry=0`` compiles the exact pre-telemetry
-    program: results are bit-for-bit identical to a build without this
-    feature.
-
-    ``spec.billing`` (a ``repro.fleet.billing`` profile or name, default
-    ``ideal``) selects the billed-duration expectation the scan's
-    ``billed_gb_s`` accumulates — the ONLY knob it touches; every other
-    metric is independent of the profile."""
+    engine consumes: ``devices`` for the sharded dispatch (function axis
+    here; see ``_chunked_summaries``); ``telemetry=S`` (static, default
+    off), S downsampled per-tick series slots plus attribution sums in the
+    scan carry — constant memory — assembled into the row's ``telemetry``
+    dict (repro.obs.telemetry), while ``telemetry=0`` compiles no telemetry
+    op at all; and ``billing`` (a ``repro.fleet.billing`` profile or name,
+    default ``ideal``), the billed-duration expectation the scan's
+    ``billed_gb_s`` accumulates — the ONLY metric it touches."""
     from repro.core.runspec import RunSpec
     spec = spec if spec is not None else RunSpec()
     if not isinstance(spec, RunSpec):

@@ -45,7 +45,7 @@ from repro.core.eventsim import SimConfig
 from repro.core.policies import init_theta
 from repro.core.policy_api import get_family
 from repro.core.simjax import (_PFLEET, JaxPolicy, _init_state, _make_step,
-                               _prep_static)
+                               _run_statics)
 from repro.core.runspec import RunSpec
 from repro.core.trace import Trace, gap_statistics, rate_matrix
 from repro.fleet.billing import (BillingProfile, apply_throttle,
@@ -84,39 +84,37 @@ def make_loss(trace: Trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),
     pad = n_chunks * trunc - n_ticks
     arr = jnp.asarray(np.concatenate(
         [arr_np, np.zeros((pad, f), arr_np.dtype)]))
-    dur, mem, cold_ticks, wbuf, cpu_consts = _prep_static(trace, policy,
-                                                          sim, dt)
+    has_fleet = fleet is not None
+    # per-TIER node rates: the spot discount applies only to the scan's
+    # spot node-seconds (ys[12]), exactly as repro.fleet.costs bills —
+    # discounting the whole fleet would overstate any partial-spot savings
+    prof = resolve_profile(billing)
+    statics = _run_statics(trace, policy, sim, dt, billing=prof,
+                           provision_s=fleet.provision_s if has_fleet
+                           else 0.0, nbins=256, n_ticks=n_ticks,
+                           warmup_frac=warmup_frac)
     lam0 = jnp.asarray(arr_np.mean(axis=0) / dt, jnp.float32)
     gq, alive_tab, tail_tab = gap_statistics(trace)
     gaps = jnp.asarray(gq, jnp.float32)
     gap_tab = jax.tree.map(lambda x: jnp.asarray(x, jnp.float32),
                            (alive_tab, tail_tab))
-    has_fleet = fleet is not None
-    prov_ticks = max(1, int(round((fleet.provision_s if has_fleet else 0.0)
-                                  / dt)))
     fl = jnp.asarray(fleet.params() if has_fleet else np.zeros(len(_PFLEET)),
                      jnp.float32)
-    warm_tick = int(n_ticks * warmup_frac)
     # padded ticks advance state but carry zero weight, like _chunk_impl
-    mask = jnp.asarray(((np.arange(n_chunks * trunc) >= warm_tick)
-                        & (np.arange(n_chunks * trunc) < n_ticks))
+    ticks = np.arange(n_chunks * trunc)
+    mask = jnp.asarray(((ticks >= statics.warm_tick) & (ticks < n_ticks))
                        .astype(np.float32))
-    # per-TIER node rates: the spot discount applies only to the scan's
-    # spot node-seconds (ys[12]), exactly as repro.fleet.costs bills —
-    # discounting the whole fleet would overstate any partial-spot savings
-    prof = resolve_profile(billing)
     od_rate = node_type.price_per_hour
     spot_rate = od_rate * (1.0 - prof.spot_discount)
-    billed_w = jnp.asarray(prof.billed_weights(trace.profile), jnp.float32)
-    dur_mean = jnp.asarray(np.asarray(dur), jnp.float32)
     family = policy.family
 
     def loss_fn(params) -> jnp.ndarray:
-        step = _make_step(arr, dur, mem, billed_w, lam0, gaps, gap_tab,
-                          params, fl, cpu_consts,
-                          float(num_nodes), family=family, dt=dt,
-                          cold_ticks=cold_ticks, wbuf=wbuf,
-                          prov_ticks=prov_ticks, has_fleet=has_fleet)
+        step = _make_step(arr, statics.dur, statics.mem, statics.billed_w,
+                          lam0, gaps, gap_tab, params, fl,
+                          statics.cpu_consts, float(num_nodes),
+                          family=family, dt=dt,
+                          cold_ticks=statics.cold_ticks, wbuf=statics.wbuf,
+                          prov_ticks=statics.prov_ticks, has_fleet=has_fleet)
 
         def tick(carry, t):
             st, a_tot, d1, d2, scalars = carry
@@ -139,7 +137,8 @@ def make_loss(trace: Trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),
             return (st, *acc), None
 
         init_nodes = fl[0] if has_fleet else jnp.asarray(float(num_nodes))
-        init = (_init_state(f, cold_ticks, wbuf, prov_ticks, init_nodes),
+        init = (_init_state(f, statics.cold_ticks, statics.wbuf,
+                            statics.prov_ticks, init_nodes),
                 jnp.zeros(f), jnp.zeros(f), jnp.zeros(f), jnp.zeros(6))
         (_, a_tot, d1, d2, scalars), _ = jax.lax.scan(
             chunk, init, jnp.arange(n_chunks))
@@ -165,7 +164,8 @@ def make_loss(trace: Trace, policy: JaxPolicy, sim: SimConfig = SimConfig(),
         # slowdown proxy: mean wait + delay-weighted mean wait per function
         mean_wait = d1 / jnp.maximum(a_tot, 1e-9)
         tail_wait = d2 / jnp.maximum(d1, 1e-9)
-        slow = 1.0 + (mean_wait + tail_wait + sim.warm_latency_s) / dur_mean
+        slow = 1.0 + (mean_wait + tail_wait + sim.warm_latency_s) \
+            / statics.dur
         wf = a_tot / (a_tot + 1.0)          # smooth min-request weighting
         slow_geo = jnp.exp((wf * jnp.log(slow)).sum()
                            / jnp.maximum(wf.sum(), 1e-9))

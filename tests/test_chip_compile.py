@@ -29,7 +29,7 @@ from repro.core.eventsim import SimConfig
 from repro.core.policies import init_theta
 from repro.core.simjax import (_PFLEET, JaxFleet, _chunk_batch,
                                _chunk_batch_fnshard, _init_state,
-                               _prep_static, stack_params)
+                               _run_statics, stack_params)
 from repro.core.trace import (KA_GRID, TraceConfig, rate_matrix, synthesize,
                               synthesize_rates)
 from repro.opt.space import DEFAULT_SPACE
@@ -72,9 +72,11 @@ def _step_args(sc, width: int, points: int, *, fleet: bool,
     small = TraceConfig(num_functions=2, duration_s=60, target_total_rps=1)
     tiny = synthesize_rates(small, dt) if sc.rate_trace else synthesize(small)
     policy = sc.policy.to_jax()
-    _, _, cold_ticks, wbuf, cpu_consts = _prep_static(tiny, policy, sim, dt)
     jf = sc.fleet or JaxFleet()
-    prov_ticks = max(1, int(round(jf.provision_s / dt))) if fleet else 1
+    st = _run_statics(tiny, policy, sim, dt, billing=None,
+                      provision_s=jf.provision_s if fleet else 0.0,
+                      nbins=NBINS, n_ticks=4800, warmup_frac=0.5)
+    cold_ticks, wbuf, prov_ticks = st.cold_ticks, st.wbuf, st.prov_ticks
     arr_dtype = np.float32 if weights else rate_matrix(tiny, dt).dtype
     f32 = np.float32
 
@@ -95,7 +97,7 @@ def _step_args(sc, width: int, points: int, *, fleet: bool,
     statics = dict(warm_tick=1000, total_ticks=4800, family=policy.family,
                    dt=dt, cold_ticks=cold_ticks, wbuf=wbuf,
                    prov_ticks=prov_ticks, telem_slots=0)
-    return (args, tuple(float(c) for c in cpu_consts), a((NBINS - 1,)),
+    return (args, tuple(float(c) for c in st.cpu_consts), a((NBINS - 1,)),
             a((), np.int32), a((width,)) if weights else None, statics)
 
 

@@ -11,7 +11,7 @@ from repro.core.control_plane import ControlPlane, SimWorkerBackend
 from repro.core.eventsim import EventSim, SimConfig
 from repro.core.metrics import compute
 from repro.core.policies import AsyncConcurrencyPolicy, SyncKeepalivePolicy
-from repro.core.simjax import JaxFleet, JaxPolicy, simulate, summarize
+from repro.core.simjax import JaxFleet, JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, synthesize
 from repro.fleet import (FleetManager, NodeFleet, NodeType,
                          ScheduleFleetPolicy, ThresholdFleetPolicy,
@@ -205,8 +205,8 @@ def test_fleet_parity_oracle_vs_simjax(trace):
     jf = JaxFleet(node_memory_mb=NODE_MB, provision_s=NT.provision_s,
                   min_nodes=1, max_nodes=64, util_target=0.7, warm_frac=0.25,
                   cooldown_s=120.0)
-    jres = simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7), fleet=jf)
-    s = summarize(jres)
+    s = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                         fleet=jf)
     fluid_cost = cost_report(
         node_seconds=s["node_seconds"], cpu_worker_overhead_s=s["cpu_worker_s"],
         cpu_master_overhead_s=s["cpu_master_s"], idle_node_share=0.0,
@@ -220,12 +220,12 @@ def test_fleet_parity_oracle_vs_simjax(trace):
 
 
 def test_simjax_fleet_capacity_caps_instances(trace):
-    tight = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
-                               fleet=JaxFleet(node_memory_mb=NODE_MB,
-                                              min_nodes=1, max_nodes=2)))
-    roomy = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
-                               fleet=JaxFleet(node_memory_mb=NODE_MB,
-                                              min_nodes=1, max_nodes=64)))
+    tight = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                             fleet=JaxFleet(node_memory_mb=NODE_MB,
+                                            min_nodes=1, max_nodes=2))
+    roomy = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                             fleet=JaxFleet(node_memory_mb=NODE_MB,
+                                            min_nodes=1, max_nodes=64))
     assert tight["nodes_mean"] <= 2.0 + 1e-6
     assert roomy["nodes_mean"] > tight["nodes_mean"]
     # capacity starvation must surface as queueing delay, not lost load
@@ -233,10 +233,10 @@ def test_simjax_fleet_capacity_caps_instances(trace):
 
 
 def test_simjax_warm_frac_adds_nodes(trace):
-    lean = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
-                              fleet=JaxFleet(node_memory_mb=NODE_MB, warm_frac=0.0)))
-    padded = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
-                                fleet=JaxFleet(node_memory_mb=NODE_MB, warm_frac=1.0)))
+    lean = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                            fleet=JaxFleet(node_memory_mb=NODE_MB, warm_frac=0.0))
+    padded = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7),
+                              fleet=JaxFleet(node_memory_mb=NODE_MB, warm_frac=1.0))
     assert padded["nodes_mean"] > lean["nodes_mean"]
     assert padded["node_seconds"] > lean["node_seconds"]
 
@@ -268,8 +268,8 @@ def test_sweep_matches_single_runs(trace):
     rows = sweep(trace, JaxPolicy(kind=0, keepalive_s=120), jf,
                  grid={"keepalive_s": [60.0, 300.0]}, node_type=NT)
     for row in rows:
-        single = summarize(simulate(
-            trace, JaxPolicy(kind=0, keepalive_s=row["keepalive_s"]), fleet=jf))
+        single = simulate_chunked(
+            trace, JaxPolicy(kind=0, keepalive_s=row["keepalive_s"]), fleet=jf)
         assert row["nodes_mean"] == pytest.approx(single["nodes_mean"], rel=1e-4)
         assert row["instances_mean"] == pytest.approx(
             single["instances_mean"], rel=1e-4)

@@ -288,3 +288,45 @@ def test_slowdown_bisection_carries_its_scope():
         p, jnp.ones(4), jnp.full(3, 0.99), jnp.zeros((3, 1)),
         jnp.ones((3, 1)), jnp.float32(100.0)).as_text(debug_info=True)
     assert "slowdown_bisect" in text
+
+
+def test_benchmark_hooks_are_called(trace, monkeypatch):
+    """The benchmark wraps engine functions by module attribute
+    (``bench.spans.WRAPPED``); each must exist and be called, through its
+    module global, by a replay, a function-sharded replay or a sweep."""
+    import importlib
+
+    from bench.spans import WRAPPED
+    calls = {}
+    for mod_name, attr, _ in WRAPPED:
+        mod = importlib.import_module(mod_name)
+        fn = getattr(mod, attr)
+
+        def counted(*args, _fn=fn, _key=(mod_name, attr), **kwargs):
+            calls[_key] = calls.get(_key, 0) + 1
+            return _fn(*args, **kwargs)
+        monkeypatch.setattr(mod, attr, counted)
+    _replay(trace)
+    _replay(trace, spec=RunSpec(devices=1))
+    evaluate_points(trace, POLICY, JaxFleet(),
+                    [{"keepalive_s": ka} for ka in (60.0, 120.0, 300.0,
+                                                    600.0)],
+                    sim=SIM, dt=1.0, warmup_frac=0.5, chunk_ticks=CHUNK)
+    for mod_name, attr, _ in WRAPPED:
+        assert calls.get((mod_name, attr), 0) >= 1, (mod_name, attr)
+
+
+def _span_tree(rec):
+    by_id = {sp.sid: sp.name for sp in rec.spans.spans}
+    return {(sp.name, by_id.get(sp.parent)) for sp in rec.spans.spans}
+
+
+def test_plain_and_cells_runs_share_one_span_tree(trace):
+    """Both engines run through one host path, so a plain replay and a
+    multi-region run record the same spans under the same parents."""
+    _, plain = _one_run(lambda: _replay(trace))
+    sc = get_scenario("region_failover")
+    traces = build_cell_traces(sc, scale=0.25)
+    sim = SimConfig(tick_s=sc.policy.tick_s)
+    _, cells = _one_run(lambda: run_cells_fluid(sc, traces, sim))
+    assert _span_tree(cells) == _span_tree(plain)

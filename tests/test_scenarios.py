@@ -1,5 +1,5 @@
-"""Scenario engine: registry, trace transforms, chunked-vs-full simulator
-agreement, and the oracle-vs-simjax parity acceptance band."""
+"""Scenario engine: registry, trace transforms, the chunked simulator's
+chunk-size invariance, and the oracle-vs-simjax parity acceptance band."""
 
 import dataclasses
 
@@ -7,8 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core.runspec import RunSpec
-from repro.core.simjax import (JaxFleet, JaxPolicy, simulate, simulate_chunked,
-                               summarize)
+from repro.core.simjax import JaxFleet, JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, merge_traces, synthesize
 from repro.scenarios import (BurstInject, PolicySpec, RateScale, Scenario,
                              Splice, TenantMerge, TimeWarp, get_scenario,
@@ -120,7 +119,7 @@ def test_merge_traces_interleaves():
 
 
 # ---------------------------------------------------------------------------
-# chunked scan vs full-history scan
+# chunked scan: the chunk size is a memory knob, not a model one
 # ---------------------------------------------------------------------------
 
 
@@ -130,17 +129,17 @@ def test_merge_traces_interleaves():
     (JaxPolicy(kind=1, window_s=60, target=0.7),
      JaxFleet(node_memory_mb=8192.0, min_nodes=1, max_nodes=32)),
 ])
-def test_chunked_matches_full_history(trace, policy, fleet):
-    """Same step math, segmented time axis + in-carry summary stats: the
-    chunked path must reproduce the full-history summary (sum-based metrics
-    to float tolerance, histogram-based slowdown within binning error)."""
-    full = summarize(simulate(trace, policy, fleet=fleet))
+def test_chunk_size_leaves_the_row_unchanged(trace, policy, fleet):
+    """Same step math, segmented time axis + in-carry summary stats: 257-tick
+    chunks (an uneven, padded tail) give the row one chunk of the whole
+    horizon gives, every float metric to float32 summation-order error."""
+    whole = simulate_chunked(trace, policy, fleet=fleet,
+                             chunk_ticks=int(trace.duration_s))
     chunk = simulate_chunked(trace, policy, fleet=fleet, chunk_ticks=257)
-    for key in ("normalized_memory", "creation_rate", "cpu_overhead",
-                "instances_mean", "nodes_mean", "node_seconds", "completed"):
-        assert chunk[key] == pytest.approx(full[key], rel=1e-3), key
-    assert chunk["slowdown_geomean_p99"] == pytest.approx(
-        full["slowdown_geomean_p99"], rel=0.05)
+    assert chunk.keys() == whole.keys()
+    for key, val in whole.items():
+        if isinstance(val, float):
+            assert chunk[key] == pytest.approx(val, rel=1e-5), key
 
 
 def test_chunked_handles_uneven_tail_chunk(trace):

@@ -7,7 +7,7 @@ from repro.core.cluster import Cluster
 from repro.core.eventsim import EventSim, SimConfig
 from repro.core.metrics import compute
 from repro.core.policies import AsyncConcurrencyPolicy, SyncKeepalivePolicy
-from repro.core.simjax import JaxPolicy, simulate, summarize
+from repro.core.simjax import JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, synthesize
 
 TC = TraceConfig(num_functions=80, duration_s=1200, target_total_rps=12, seed=11)
@@ -19,7 +19,7 @@ def trace():
 
 
 def test_simjax_invariants(trace):
-    s = summarize(simulate(trace, JaxPolicy(kind=0, keepalive_s=120)))
+    s = simulate_chunked(trace, JaxPolicy(kind=0, keepalive_s=120))
     assert s["slowdown_geomean_p99"] >= 1.0
     assert s["normalized_memory"] >= 1.0
     assert s["creation_rate"] >= 0.0
@@ -27,7 +27,7 @@ def test_simjax_invariants(trace):
 
 
 def test_simjax_keepalive_monotone(trace):
-    rows = [summarize(simulate(trace, JaxPolicy(kind=0, keepalive_s=ka)))
+    rows = [simulate_chunked(trace, JaxPolicy(kind=0, keepalive_s=ka))
             for ka in (30, 120, 600)]
     mem = [r["normalized_memory"] for r in rows]
     rate = [r["creation_rate"] for r in rows]
@@ -37,7 +37,7 @@ def test_simjax_keepalive_monotone(trace):
 
 @pytest.mark.slow
 def test_simjax_window_monotone(trace):
-    rows = [summarize(simulate(trace, JaxPolicy(kind=1, window_s=w, target=0.7)))
+    rows = [simulate_chunked(trace, JaxPolicy(kind=1, window_s=w, target=0.7))
             for w in (30, 120, 600)]
     rate = [r["creation_rate"] for r in rows]
     assert rate == sorted(rate, reverse=True)
@@ -46,8 +46,8 @@ def test_simjax_window_monotone(trace):
 
 
 def test_simjax_target_direction(trace):
-    lo = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.5)))
-    hi = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=1.0)))
+    lo = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.5))
+    hi = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=1.0))
     # smaller target -> more instances -> more memory (paper Table 1)
     assert lo["normalized_memory"] >= hi["normalized_memory"]
     assert lo["instances_mean"] >= hi["instances_mean"]
@@ -61,7 +61,7 @@ def test_simjax_tracks_oracle_trends(trace):
     oracle = [compute(EventSim(trace, Cluster(8),
                                lambda f, ka=ka: SyncKeepalivePolicy(ka)).run())
               for ka in kas]
-    fluid = [summarize(simulate(trace, JaxPolicy(kind=0, keepalive_s=ka)))
+    fluid = [simulate_chunked(trace, JaxPolicy(kind=0, keepalive_s=ka))
              for ka in kas]
     for key_o, key_f in [("normalized_memory", "normalized_memory"),
                          ("creation_rate", "creation_rate"),
@@ -75,7 +75,7 @@ def test_simjax_scales_to_thousands_of_functions():
     tc = TraceConfig(num_functions=2000, duration_s=600, target_total_rps=300,
                      seed=1)
     trace = synthesize(tc)
-    s = summarize(simulate(trace, JaxPolicy(kind=1, window_s=60, target=0.7)))
+    s = simulate_chunked(trace, JaxPolicy(kind=1, window_s=60, target=0.7))
     assert np.isfinite(s["slowdown_geomean_p99"])
     assert s["instances_mean"] > 10
 

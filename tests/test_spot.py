@@ -11,7 +11,7 @@ from repro.core.cluster import GONE, Cluster
 from repro.core.eventsim import EventSim, SimConfig
 from repro.core.metrics import compute
 from repro.core.policies import SpotAwarePolicy, SyncKeepalivePolicy
-from repro.core.simjax import JaxFleet, JaxPolicy, simulate, summarize
+from repro.core.simjax import JaxFleet, JaxPolicy, simulate_chunked
 from repro.core.trace import TraceConfig, synthesize
 from repro.fleet import (NodeFleet, NodeType, PriceBook,
                          UtilizationFleetPolicy, cost_from_sim, cost_report)
@@ -168,12 +168,12 @@ def test_zero_spot_oracle_bit_for_bit(trace):
 
 def test_zero_spot_simjax_bit_for_bit(trace):
     jf = JaxFleet(node_memory_mb=NODE_MB)
-    sync = summarize(simulate(trace, JaxPolicy(family="sync",
-                                               keepalive_s=600), fleet=jf))
-    spot0 = summarize(simulate(
+    sync = simulate_chunked(trace, JaxPolicy(family="sync",
+                                             keepalive_s=600), fleet=jf)
+    spot0 = simulate_chunked(
         trace, JaxPolicy(family="spot_aware", keepalive_s=600,
                          extra={"spot_fraction": 0.0,
-                                "hazard_per_hour": 0.0}), fleet=jf))
+                                "hazard_per_hour": 0.0}), fleet=jf)
     for k in sync:
         assert sync[k] == spot0[k], k
     assert spot0["spot_nodes_mean"] == 0.0
@@ -183,14 +183,14 @@ def test_simjax_hazard_causes_storm(trace):
     """The traced eviction flux produces the storm signature: more
     creations, worse tail, a billed spot share."""
     jf = JaxFleet(node_memory_mb=NODE_MB)
-    base = summarize(simulate(
+    base = simulate_chunked(
         trace, JaxPolicy(family="spot_aware", keepalive_s=600,
                          extra={"spot_fraction": 0.6,
-                                "hazard_per_hour": 0.0}), fleet=jf))
-    storm = summarize(simulate(
+                                "hazard_per_hour": 0.0}), fleet=jf)
+    storm = simulate_chunked(
         trace, JaxPolicy(family="spot_aware", keepalive_s=600,
                          extra={"spot_fraction": 0.6,
-                                "hazard_per_hour": 20.0}), fleet=jf))
+                                "hazard_per_hour": 20.0}), fleet=jf)
     assert storm["creation_rate"] > base["creation_rate"]
     assert storm["slowdown_geomean_p99"] >= base["slowdown_geomean_p99"]
     assert storm["spot_nodes_mean"] > 0.0
@@ -326,12 +326,12 @@ def test_spot_headroom_holds_extra_warm(trace):
     than plain sync under the same (hazardless) conditions when the
     declared hazard is large."""
     jf = JaxFleet(node_memory_mb=NODE_MB)
-    lean = summarize(simulate(
+    lean = simulate_chunked(
         trace, JaxPolicy(family="spot_aware", keepalive_s=600,
                          extra={"spot_fraction": 0.0,
-                                "hazard_per_hour": 0.0}), fleet=jf))
-    padded = summarize(simulate(
+                                "hazard_per_hour": 0.0}), fleet=jf)
+    padded = simulate_chunked(
         trace, JaxPolicy(family="spot_aware", keepalive_s=600,
                          extra={"spot_fraction": 1.0,
-                                "hazard_per_hour": 60.0}), fleet=jf))
+                                "hazard_per_hour": 60.0}), fleet=jf)
     assert padded["instances_mean"] > lean["instances_mean"]
